@@ -17,6 +17,8 @@ import time
 
 import jax
 
+from repro import compile_cache
+
 BASES = ("memristive", "dram")
 
 
@@ -44,24 +46,16 @@ def bases_from_args(args) -> tuple[str, ...]:
 def run_cli(run_fn, argv=None) -> None:
     """Shared benchmark main: parse the common flags, run, emit CSV."""
     args = parse_args(argv)
+    compile_cache.enable()
     emit(run_fn(bases=bases_from_args(args), passes=passes_from_args(args)))
 
 
 def time_fn(fn, *args, warmup: int = 1, iters: int = 3) -> float:
+    """Median wall time per call in microseconds (jit-compiled; the warmup
+    calls absorb compilation)."""
     assert iters >= 1
-    """Median wall time per call in microseconds (CPU; jit-compiled).
-    Retries once on transient XLA-CPU compile failures (seen under heavy
-    concurrent compilation on 1-core containers)."""
-    for attempt in (0, 1):
-        try:
-            for _ in range(warmup):
-                out = fn(*args)
-                jax.block_until_ready(out)
-            break
-        except Exception:  # noqa: BLE001 — transient "Unknown MLIR failure"
-            if attempt:
-                raise
-            jax.clear_caches()
+    for _ in range(warmup):
+        jax.block_until_ready(fn(*args))
     ts = []
     for _ in range(iters):
         t0 = time.perf_counter()

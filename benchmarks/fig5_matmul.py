@@ -4,7 +4,8 @@ as data reuse O(n) grows.
 Reproduces the paper's crossover: for small n the accelerator is
 memory-bound and PIM competes; by n≈128 reuse lifts the accelerator to
 compute-bound and PIM loses (paper §4).  The us_per_call column times our
-MatPIM-schedule Pallas kernel (interpret mode) on a small instance.
+MatPIM-schedule Pallas kernel on a small instance (interpret mode on the
+CPU, the compiled kernel on a TPU).
 """
 
 from __future__ import annotations

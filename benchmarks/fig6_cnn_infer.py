@@ -77,8 +77,11 @@ def run(train: bool = False) -> list[dict]:
 
 
 def main():
+    from repro import compile_cache
+
     from .common import emit
 
+    compile_cache.enable()
     emit(run(train=False))
 
 

@@ -10,8 +10,11 @@ def run() -> list[dict]:
 
 
 def main():
+    from repro import compile_cache
+
     from .common import emit
 
+    compile_cache.enable()
     emit(run())
 
 

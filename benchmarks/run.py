@@ -23,6 +23,8 @@ import json
 import sys
 import traceback
 
+from repro import compile_cache
+
 
 def write_exec_json(path: str) -> list[dict]:
     """Run the executor-mode benchmark and write its rows to ``path``."""
@@ -46,6 +48,7 @@ def main(argv=None) -> None:
              "(gates, num_cols, waves, us per executor mode) as JSON")
     args = parser.parse_args(argv)
 
+    compile_cache.enable()
     if args.json is not None:
         write_exec_json(args.json)
         return

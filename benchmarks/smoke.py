@@ -8,14 +8,13 @@ particular performance number.  The compile-cache hit/miss counters are
 printed at the end so cache regressions (e.g. a wrapper recompiling what
 ``compile_op`` already built) are visible in CI logs.
 
-One performance number *is* asserted: the executor-mode benchmark
-(``exec_modes``, emitted to ``BENCH_exec.json``) must show the
-``pallas-unrolled`` wave-scheduled kernel beating the ``pallas-loop``
-fori_loop kernel on the f32 fused MAC — the perf trajectory this PR seeds.
+The executor-mode benchmark (``exec_modes``, emitted to
+``BENCH_exec.json``) must produce its f32 MAC rows with their exact schedule
+counts.  Its CPU timings are not checked: on the CPU both Pallas kernels run
+in interpret mode, which says nothing about their speed on a TPU.
 
 Usage: ``PYTHONPATH=src python -m benchmarks.smoke``  (exits non-zero on any
-exception, empty table, row with missing values, or executor perf
-regression).
+exception, empty table or row with missing values).
 """
 
 from __future__ import annotations
@@ -54,24 +53,22 @@ def check(name: str, rows: list[dict]) -> None:
 
 
 def check_exec(rows: list[dict]) -> None:
-    """The unrolled kernel must beat the fori_loop kernel on the f32 MAC."""
-    us = {r["name"]: float(r["us_per_call"]) for r in rows}
-    loop = us.get("exec/f32_mac/memristive/pallas-loop")
-    unrolled = us.get("exec/f32_mac/memristive/pallas-unrolled")
-    if loop is None or unrolled is None:
-        raise SystemExit("smoke: exec_modes is missing the f32 MAC rows")
-    if unrolled >= loop:
-        raise SystemExit(
-            f"smoke: pallas-unrolled ({unrolled:.0f}us) is not faster than "
-            f"the fori_loop kernel ({loop:.0f}us) on the f32 fused MAC")
-    print(f"smoke: exec ok (f32 MAC unrolled {unrolled:.0f}us vs "
-          f"loop {loop:.0f}us, {loop / unrolled:.1f}x)", file=sys.stderr)
+    """Every executor mode ran the f32 MAC and reported its counts."""
+    names = {r["name"]: r for r in rows}
+    for mode in ("pallas-loop", "pallas-unrolled"):
+        row = names.get(f"exec/f32_mac/memristive/{mode}")
+        if row is None or not all(row.get(k) for k in ("gates", "num_cols", "waves")):
+            raise SystemExit(f"smoke: exec_modes is missing the f32 MAC {mode} row")
+    print(f"smoke: exec ok ({len(rows)} rows)", file=sys.stderr)
 
 
 def main() -> None:
+    from repro import compile_cache
+
     from .common import emit
     from .run import write_exec_json
 
+    compile_cache.enable()
     for name, mod in (("fig3_arith", fig3_arith), ("fig4_cc", fig4_cc),
                       ("fig_fused", fig_fused), ("fig5_matmul", fig5_matmul)):
         rows = mod.run()

@@ -13,11 +13,11 @@ in-memory property the paper models.
 Two executor modes share the registry (DESIGN.md §5):
 
 * ``loop`` — the original ``fori_loop`` kernel: one gate per iteration,
-  dynamic single-row ``pl.load``/``pl.store`` plus a five-deep ``jnp.where``
-  opcode select, and the five gate arrays shipped to the device.  O(1)
-  compile in schedule length, but each gate pays dynamic-indexing and
-  select overhead — orders of magnitude slower than the bitwise VPU ops it
-  dispatches.
+  a dynamic single-row read and write of the state plus a five-deep
+  ``jnp.where`` opcode select, with the five gate arrays held in SMEM (5
+  int32 words per gate; v5e's 1 MiB of SMEM holds schedules of up to about
+  50k gates, and the TPU compiler refuses longer ones).  O(1) compile in
+  schedule length, but each gate pays dynamic-indexing and select overhead.
 * ``unrolled`` — a **wave-scheduled straight-line** kernel generated from
   the fact that ``(op, a, b, c, o)`` are static per ``CompiledSchedule``:
   the body is Python-unrolled bitwise ops on fixed ``state[col]`` indices —
@@ -27,17 +27,19 @@ Two executor modes share the registry (DESIGN.md §5):
   chunk is a batch of mutually independent VPU ops; long schedules are
   split into segments of ``UNROLL_SEGMENT_GATES`` at chunk boundaries
   (XLA compile time is superlinear in straight-line length) with the
-  column state threaded between segment kernels.  In ``interpret`` mode the
-  identical generated body runs as a plain jit — skipping the
-  ``pallas_call`` emulation layer, which only adds tracing overhead on CPU;
-  on hardware each segment is a ``pl.pallas_call`` with the grid over
-  word-blocks and the state block aliased in/out.
+  column state threaded between segment kernels.  Each segment is a
+  ``pl.pallas_call`` with the grid over word-blocks and the state block
+  aliased in/out.
+
+Pallas runs in interpret mode exactly when the input planes live on the CPU
+(``repro.kernels.interpret_mode``), so tests on the CPU trace the same kernel
+bodies that the TPU compiles.
 
 The ``pallas`` backend picks the mode automatically by gate count
 (``UNROLL_AUTO_MAX_GATES``): short schedules unroll, very long ones fall
 back to the loop kernel.  ``pallas-unrolled`` / ``pallas-loop`` force one
-mode (the CI perf gate in ``benchmarks/smoke.py`` races them on the f32
-fused MAC).  Per-schedule artifacts — the gate arrays and their device
+mode (``benchmarks/exec_modes.py`` runs both on the f32 fused MAC).
+Per-schedule artifacts — the gate arrays and their device
 upload for the loop kernel, the wave-chunked segments for the unrolled
 kernel — are cached by schedule key, so repeat dispatches stop rebuilding
 and re-transferring them.
@@ -69,6 +71,7 @@ from repro.core.machine import (
     Schedule,
     operand_slots,
 )
+from repro.kernels import interpret_mode
 
 BLOCK_WORDS = 256
 UMAX32 = 0xFFFFFFFF  # python int: folded into the kernel, not a captured array
@@ -104,9 +107,9 @@ def _kernel(op_ref, a_ref, b_ref, c_ref, o_ref, in_ref, out_ref, state, *,
         b = b_ref[g]
         c = c_ref[g]
         o = o_ref[g]
-        va = pl.load(state, (pl.dslice(a, 1), slice(None)))
-        vb = pl.load(state, (pl.dslice(b, 1), slice(None)))
-        vc = pl.load(state, (pl.dslice(c, 1), slice(None)))
+        va = state[pl.ds(a, 1), :]
+        vb = state[pl.ds(b, 1), :]
+        vc = state[pl.ds(c, 1), :]
         nor = ~(va | vb)
         maj = (va & vb) | (va & vc) | (vb & vc)
         res = jnp.where(
@@ -118,7 +121,7 @@ def _kernel(op_ref, a_ref, b_ref, c_ref, o_ref, in_ref, out_ref, state, *,
                                                     jnp.full_like(nor, UMAX32),
                                                     va)))),
         )
-        pl.store(state, (pl.dslice(o, 1), slice(None)), res)
+        state[pl.ds(o, 1), :] = res
         return 0
 
     jax.lax.fori_loop(0, n_gates, body, 0)
@@ -136,16 +139,13 @@ def _run(op, a, b, c, o, planes, *, schedule_key, gen, interpret):
     output_slots = compiled.output_slots
     n_in, W = planes.shape
     n_out = len(output_slots)
-    grid = (W // BLOCK_WORDS,)
+    # The gate arrays are read one scalar per iteration at a dynamic index,
+    # which Mosaic allows from SMEM but not from VMEM vectors.
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         functools.partial(_kernel, input_slots=tuple(input_slots), output_slots=tuple(output_slots)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((op.shape[0],), lambda i: (0,)),
-            pl.BlockSpec((a.shape[0],), lambda i: (0,)),
-            pl.BlockSpec((b.shape[0],), lambda i: (0,)),
-            pl.BlockSpec((c.shape[0],), lambda i: (0,)),
-            pl.BlockSpec((o.shape[0],), lambda i: (0,)),
+        grid=(W // BLOCK_WORDS,),
+        in_specs=[smem] * 5 + [
             pl.BlockSpec((n_in, BLOCK_WORDS), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((n_out, BLOCK_WORDS), lambda i: (0, i)),
@@ -237,20 +237,17 @@ def _unrolled_segment_kernel(state_ref, out_ref, *, chunks, num_cols):
         out_ref[i, :] = cols[i]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("schedule_key", "gen", "seg", "interpret"),
-                   donate_argnums=0)
-def _run_unrolled_segment(state, *, schedule_key, gen, seg, interpret):
+# XLA-CPU's MLIR fusion emitter fails ("Unknown MLIR failure") on the deep,
+# multi-use NOR full-adder DAGs that fuse inside a segment; its classic
+# emitter compiles them.  The option is read only by the CPU compiler.
+_SEGMENT_COMPILER_OPTIONS = {"xla_cpu_use_fusion_emitters": False}
+
+
+def _unrolled_segment(state, *, schedule_key, gen, seg, interpret):
     # `gen` bumps when a different schedule is registered under this key, so
     # traces that baked the old gate list are never reused.
     chunks = _segment_cache(schedule_key)[seg]
     num_cols, W = state.shape
-    if interpret:
-        # Same generated body, plain jit: pallas_call's interpret emulation
-        # only adds per-op tracing cost on CPU.
-        cols = [state[i] for i in range(num_cols)]
-        _emit_chunks(cols, chunks)
-        return jnp.stack(cols)
     return pl.pallas_call(
         functools.partial(_unrolled_segment_kernel, chunks=chunks,
                           num_cols=num_cols),
@@ -259,8 +256,17 @@ def _run_unrolled_segment(state, *, schedule_key, gen, seg, interpret):
         out_specs=pl.BlockSpec((num_cols, BLOCK_WORDS), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((num_cols, W), jnp.uint32),
         input_output_aliases={0: 0},
-        interpret=False,
+        interpret=interpret,
     )(state)
+
+
+_SEGMENT_JIT = dict(static_argnames=("schedule_key", "gen", "seg", "interpret"),
+                    donate_argnums=0)
+_run_unrolled_segment = jax.jit(_unrolled_segment, **_SEGMENT_JIT,
+                                compiler_options=_SEGMENT_COMPILER_OPTIONS)
+# A jit nested in the caller's jit may not carry compiler options; there the
+# caller's compile settings apply.
+_run_unrolled_segment_nested = jax.jit(_unrolled_segment, **_SEGMENT_JIT)
 
 
 def _run_unrolled(compiled: ir.CompiledSchedule, key: str, planes, interpret):
@@ -268,9 +274,11 @@ def _run_unrolled(compiled: ir.CompiledSchedule, key: str, planes, interpret):
     state = jnp.zeros((compiled.num_cols, planes.shape[1]), jnp.uint32)
     state = state.at[jnp.asarray(compiled.input_slots)].set(
         jnp.asarray(planes, jnp.uint32))
+    run = (_run_unrolled_segment_nested if isinstance(planes, jax.core.Tracer)
+           else _run_unrolled_segment)
     for seg in range(len(_segment_cache(key))):
-        state = _run_unrolled_segment(
-            state, schedule_key=key, gen=gen, seg=seg, interpret=interpret)
+        state = run(state, schedule_key=key, gen=gen, seg=seg,
+                    interpret=interpret)
     return state[jnp.asarray(compiled.output_slots)]
 
 
@@ -299,8 +307,11 @@ def _invalidate(key: str) -> None:
 def _gate_arrays(key: str) -> tuple:
     arrays = _GATE_ARRAYS.get(key)
     if arrays is None:
-        arrays = _GATE_ARRAYS[key] = tuple(
-            jax.device_put(a) for a in _SCHEDULES[key].as_arrays())
+        # Concrete device arrays even when first built under a caller's jit,
+        # so no tracer is cached.
+        with jax.ensure_compile_time_eval():
+            arrays = _GATE_ARRAYS[key] = tuple(
+                jax.device_put(a) for a in _SCHEDULES[key].as_arrays())
     return arrays
 
 
@@ -340,7 +351,7 @@ def resolve_mode(compiled: ir.CompiledSchedule, mode: str = "auto") -> str:
     return mode
 
 
-def run_schedule(key: str, planes: jnp.ndarray, interpret: bool = True,
+def run_schedule(key: str, planes: jnp.ndarray,
                  mode: str = "auto") -> jnp.ndarray:
     """Execute registered schedule ``key`` over stacked input planes.
 
@@ -349,8 +360,10 @@ def run_schedule(key: str, planes: jnp.ndarray, interpret: bool = True,
     ``[n_outputs, W]``.  W is padded to a BLOCK_WORDS multiple internally.
     ``mode`` selects the kernel: ``auto`` (by gate count), ``unrolled``
     (wave-scheduled straight line) or ``loop`` (fori_loop dispatch).
+    Pallas runs in interpret mode exactly when ``planes`` live on the CPU.
     """
     compiled = _SCHEDULES[key]
+    planes = jnp.asarray(planes)
     if planes.shape[0] != len(compiled.input_slots):
         expected = {name: len(cols)
                     for name, cols in sorted(compiled.input_cols.items())}
@@ -362,6 +375,7 @@ def run_schedule(key: str, planes: jnp.ndarray, interpret: bool = True,
     pad = (-W) % BLOCK_WORDS
     if pad:
         planes = jnp.pad(planes, ((0, 0), (0, pad)))
+    interpret = interpret_mode(planes)
     if resolve_mode(compiled, mode) == "unrolled":
         out = _run_unrolled(compiled, key, planes, interpret)
     else:
@@ -373,19 +387,17 @@ def run_schedule(key: str, planes: jnp.ndarray, interpret: bool = True,
 
 class PallasBackend(ir.Backend):
     """TPU executor: one VMEM-resident crossbar per word-block, kernel mode
-    chosen by gate count (interpret mode executes the same generated gate
-    sequence on CPU).  ``opts['mode']`` overrides the selection per call."""
+    chosen by gate count (on the CPU the same kernels run in Pallas
+    interpret mode).  ``opts['mode']`` overrides the selection per call."""
 
     name = "pallas"
     mode = "auto"
 
-    def run(self, compiled, planes=None, interpret: bool = True,
-            mode: str | None = None, **opts):
+    def run(self, compiled, planes=None, mode: str | None = None, **opts):
         if planes is None:
             raise ValueError(f"{self.name} backend needs input planes")
         key = register_compiled(compiled)
-        out = run_schedule(key, planes, interpret=interpret,
-                           mode=mode or self.mode)
+        out = run_schedule(key, planes, mode=mode or self.mode)
         return ir.ExecutionResult(out, self.cost(compiled))
 
 

@@ -33,8 +33,13 @@ def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # Mosaic's default contracts f32 operands in one bf16 MXU pass (max
+    # relative error ~2e-3 on a v5e); HIGHEST keeps f32 products exact.
+    # Mosaic rejects HIGHEST for bf16 operands, whose products are exact.
+    f32 = a_ref.dtype == jnp.float32
     acc_ref[...] += jnp.dot(
-        a_ref[0], b_ref[0], preferred_element_type=jnp.float32
+        a_ref[0], b_ref[0], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST if f32 else None,
     )
 
     @pl.when(pl.program_id(2) == n_k - 1)
@@ -52,13 +57,14 @@ def matmul(
     bm: int = DEFAULT_BM,
     bk: int = DEFAULT_BK,
     bn: int = DEFAULT_BN,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Batched matmul ``[G, M, K] @ [G, K, N] -> [G, M, N]`` (fp32 accumulate).
 
     Grid: (G·M/bm, N/bn, K/bk); K innermost so the fp32 accumulator tile in
     VMEM scratch is revisited serially — the MatPIM serial-accumulation
-    schedule."""
+    schedule.  ``interpret`` is static; ``ops.pim_matmul_op`` derives it from
+    the operands' platform."""
     G, M, K = a.shape
     G2, K2, N = b.shape
     assert G == G2 and K == K2

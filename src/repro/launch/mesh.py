@@ -10,28 +10,11 @@ state.  Mesh axes:
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: no AxisType and no axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_abstract_mesh(shape, axes):
-    """Version-compatible ``AbstractMesh``: new jax takes ``(sizes, names)``,
-    jax <= 0.4.x takes a single ``((name, size), ...)`` tuple."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -39,9 +39,7 @@ def compressed_psum_mean(g: jnp.ndarray, err: jnp.ndarray, axis_name: str):
     """Error-feedback int8 mean-reduce over ``axis_name`` (shard_map only).
 
     Returns (mean gradient, new local error residual)."""
-    # jax.lax.axis_size is missing on older jax; psum(1) is the portable size
-    _axis_size = getattr(jax.lax, "axis_size", None)
-    D = _axis_size(axis_name) if _axis_size else jax.lax.psum(1, axis_name)
+    D = jax.lax.axis_size(axis_name)
     n = g.size
     flat = g.reshape(-1).astype(jnp.float32) + err.reshape(-1)
     seg = -(-n // (D * CHUNK)) * CHUNK  # segment length, CHUNK-aligned

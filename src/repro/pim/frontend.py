@@ -220,8 +220,7 @@ class CompiledPimFunction:
 
     def __call__(self, *arrays, basis: str = "memristive",
                  passes: tuple[str, ...] = ir.DEFAULT_PASSES,
-                 backend: str | None = None, interpret: bool = True,
-                 mode: str | None = None):
+                 backend: str | None = None, mode: str | None = None):
         if len(arrays) != len(self.in_types):
             raise TypeError(
                 f"expected {len(self.in_types)} arrays, got {len(arrays)}")
@@ -237,8 +236,7 @@ class CompiledPimFunction:
                 f"executor mode {mode!r} only applies to the pallas "
                 f"backends, not {name!r}")
         opts = {} if mode is None else {"mode": mode}
-        out = ir.get_backend(name).run(
-            compiled, planes, interpret=interpret, **opts).planes
+        out = ir.get_backend(name).run(compiled, planes, **opts).planes
         results, i = [], 0
         for t in self.out_types:
             results.append(t.from_planes([out[i + j] for j in range(t.width)], n))
@@ -287,7 +285,8 @@ def compile(fn, dtype, backend: str = "pallas") -> CompiledPimFunction:  # noqa:
     per-argument types (both operands of every op must agree — there is no
     implicit promotion).  The returned function packs arrays to bit-planes,
     executes the fused program on the requested executor backend
-    (``pallas`` by default, ``interpret=True`` on CPU) and unpacks the
-    result; ``.cost(basis=...)`` prices it analytically on either basis.
+    (``pallas`` by default; Pallas interpret mode when the arrays are on the
+    CPU) and unpacks the result; ``.cost(basis=...)`` prices it analytically
+    on either basis.
     """
     return dataclasses.replace(trace(fn, dtype), backend=backend)

@@ -10,18 +10,9 @@ import numpy as np
 SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import inspect
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.parallel.compression import compressed_psum_mean
-
-try:  # jax >= 0.6 promotes shard_map to the top level
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma
-_ck = "check_vma" if "check_vma" in inspect.signature(shard_map).parameters else "check_rep"
 
 mesh = jax.make_mesh((8,), ("data",))
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 1000))
@@ -29,9 +20,9 @@ err = jnp.zeros((8, 1000))
 
 @jax.jit
 def run(g, err):
-    f = shard_map(lambda gl, el: compressed_psum_mean(gl[0], el[0], "data"),
-                  mesh=mesh, in_specs=(P("data", None), P("data", None)),
-                  out_specs=(P(None), P("data")), **{_ck: False})
+    f = jax.shard_map(lambda gl, el: compressed_psum_mean(gl[0], el[0], "data"),
+                      mesh=mesh, in_specs=(P("data", None), P("data", None)),
+                      out_specs=(P(None), P("data")), check_vma=False)
     return f(g, err)
 
 mean, new_err = run(g, err)

@@ -103,11 +103,24 @@ def test_fused_mac_unrolled_bit_exact(dtype, basis):
     assert np.array_equal(vl, vi), (dtype, basis)
 
 
+@pytest.mark.parametrize("mode", ["unrolled", "loop"])
+def test_fused_mac_under_outer_jit(mode):
+    """A program called inside the caller's ``jax.jit`` runs the same
+    kernels (traced arrays pick interpret mode from the default backend)."""
+    import jax
+
+    mac = pim.compile(_MAC, dtype=pim.int8)
+    x = jnp.asarray(np.arange(-40, 40, dtype=np.int32))
+    jitted = jax.jit(lambda a, b, c: mac(a, b, c, basis="dram", mode=mode))
+    got = np.asarray(jitted(x, x[::-1], x))
+    exp = np.asarray(mac(x, x[::-1], x, basis="dram", mode=mode))
+    assert np.array_equal(got, exp)
+
+
 def test_fused_f32_mac_unrolled_bit_exact():
     """The flagship 13k-gate f32 fused MAC: the forced straight-line kernel
     (multi-segment) reproduces the interpreter bit-for-bit.  One basis —
-    this is the most expensive straight-line compile in the suite; the CI
-    smoke perf gate races the same schedule."""
+    this is the most expensive straight-line compile in the suite."""
     mac = pim.compile(_MAC, dtype=pim.f32)
     rng = np.random.default_rng(7)
     args = [jnp.asarray(

@@ -1,5 +1,5 @@
 """Per-kernel validation: shape/dtype sweeps vs the pure-jnp ref oracles
-(interpret=True executes the Pallas kernel body on CPU)."""
+(on the CPU the Pallas kernel bodies run in interpret mode)."""
 
 import jax
 import jax.numpy as jnp

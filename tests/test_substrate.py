@@ -14,10 +14,9 @@ try:  # hypothesis is optional: fall back to deterministic seeded cases
 except ImportError:
     from _hyp import given, settings, st
 
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_smoke_config
-from repro.launch.mesh import make_abstract_mesh
 from repro.data.pipeline import DataConfig, MemmapCorpus, SyntheticStream
 from repro.checkpoint import store
 from repro.optim import adamw, adafactor, clip_by_global_norm, global_norm, warmup_cosine
@@ -134,7 +133,7 @@ def test_checkpoint_restores_subtree_and_defaults():
 
 # -------------------------------------------------------------- sharding
 
-MESH = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_param_specs_tp_rules():
@@ -171,7 +170,7 @@ def test_sanitize_drops_nondividing_axes():
 
 
 def test_filter_spec_removes_missing_axes():
-    single = make_abstract_mesh((16, 16), ("data", "model"))
+    single = AbstractMesh((16, 16), ("data", "model"))
     f = sharding.filter_spec(P(("pod", "data"), "model"), single)
     assert tuple(f) == ("data", "model")
 
