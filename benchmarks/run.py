@@ -6,7 +6,6 @@
   fig5_matmul     — §4 batched matmul reuse crossover
   fig6_cnn_infer  — §5 CNN inference
   fig7_cnn_train  — §5 CNN training
-  roofline_table  — deliverable (g): per-cell three-term roofline + Fig-8 verdicts
 
 Prints ``name,us_per_call,derived`` CSV.  The executor-mode shootout
 (``exec_modes``, unrolled vs fori_loop) is not part of the default sweep —
@@ -54,12 +53,12 @@ def main(argv=None) -> None:
         return
 
     from . import (fig3_arith, fig4_cc, fig5_matmul, fig6_cnn_infer,
-                   fig7_cnn_train, fig_fused, roofline_table)
+                   fig7_cnn_train, fig_fused)
     from .common import emit
 
     failures = 0
     for mod in (fig3_arith, fig4_cc, fig_fused, fig5_matmul, fig6_cnn_infer,
-                fig7_cnn_train, roofline_table):
+                fig7_cnn_train):
         try:
             emit(mod.run())
         except Exception:  # noqa: BLE001

@@ -86,6 +86,10 @@ UNROLL_AUTO_MAX_GATES = 1024
 # chunk edges.  ~4 s of XLA-CPU compile per segment, amortized by the
 # per-key segment cache.
 UNROLL_SEGMENT_GATES = 1024
+# Kernel names in the compiled HLO and a profiler trace: the loop kernel,
+# and ``<SEGMENT_KERNEL>_<k>`` for the k-th unrolled segment.
+LOOP_KERNEL = "pim_loop"
+SEGMENT_KERNEL = "pim_segment"
 
 
 # ---------------------------------------------------------------------------
@@ -152,6 +156,7 @@ def _run(op, a, b, c, o, planes, *, schedule_key, gen, interpret):
         out_shape=jax.ShapeDtypeStruct((n_out, W), jnp.uint32),
         scratch_shapes=[pltpu.VMEM((compiled.num_cols, BLOCK_WORDS), jnp.uint32)],
         interpret=interpret,
+        name=LOOP_KERNEL,
     )(op, a, b, c, o, planes)
 
 
@@ -257,6 +262,7 @@ def _unrolled_segment(state, *, schedule_key, gen, seg, interpret):
         out_shape=jax.ShapeDtypeStruct((num_cols, W), jnp.uint32),
         input_output_aliases={0: 0},
         interpret=interpret,
+        name=f"{SEGMENT_KERNEL}_{seg}",
     )(state)
 
 

@@ -33,10 +33,16 @@ import inspect
 import re
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import aritpim, ir
 from repro.core.bitplanes import PimType
+
+# Profiler spans of one call (``CompiledPimFunction.__call__``), in order.
+PACK_SPAN = "pim.pack"
+EXECUTE_SPAN = "pim.execute"
+UNPACK_SPAN = "pim.unpack"
 
 
 class TraceError(TypeError):
@@ -221,27 +227,43 @@ class CompiledPimFunction:
     def __call__(self, *arrays, basis: str = "memristive",
                  passes: tuple[str, ...] = ir.DEFAULT_PASSES,
                  backend: str | None = None, mode: str | None = None):
+        """Pack ``arrays`` to bit-planes, run the compiled program, unpack.
+
+        Each phase is a ``jax.profiler.TraceAnnotation`` span, so a
+        profiler trace shows where a call's host time goes:
+        ``PACK_SPAN`` (cast, ``to_planes``, stacking the input planes),
+        ``EXECUTE_SPAN`` (the compile-cache lookup and the backend's
+        ``run``: padding, placement, every kernel launch, the trim) and
+        ``UNPACK_SPAN`` (``from_planes`` and the result tuple).  Nothing
+        here waits for a result, so the spans time the host's enqueue of
+        device work (which stalls while the device's queue is full), not
+        the device; under a caller's ``jax.jit`` they time tracing.  With
+        no profiler running a span costs about a microsecond."""
         if len(arrays) != len(self.in_types):
             raise TypeError(
                 f"expected {len(self.in_types)} arrays, got {len(arrays)}")
-        arrays = [t.cast(x) for t, x in zip(self.in_types, arrays)]
-        n = arrays[0].shape[0]
-        planes = jnp.stack(
-            [p for t, x in zip(self.in_types, arrays) for p in t.to_planes(x)]
-        )
-        compiled = self.compiled(basis, passes)
-        name = backend or self.backend
-        if mode is not None and not name.startswith("pallas"):
-            raise ValueError(
-                f"executor mode {mode!r} only applies to the pallas "
-                f"backends, not {name!r}")
-        opts = {} if mode is None else {"mode": mode}
-        out = ir.get_backend(name).run(compiled, planes, **opts).planes
-        results, i = [], 0
-        for t in self.out_types:
-            results.append(t.from_planes([out[i + j] for j in range(t.width)], n))
-            i += t.width
-        return results[0] if len(results) == 1 else tuple(results)
+        with jax.profiler.TraceAnnotation(PACK_SPAN):
+            arrays = [t.cast(x) for t, x in zip(self.in_types, arrays)]
+            n = arrays[0].shape[0]
+            planes = jnp.stack(
+                [p for t, x in zip(self.in_types, arrays)
+                 for p in t.to_planes(x)])
+        with jax.profiler.TraceAnnotation(EXECUTE_SPAN):
+            compiled = self.compiled(basis, passes)
+            name = backend or self.backend
+            if mode is not None and not name.startswith("pallas"):
+                raise ValueError(
+                    f"executor mode {mode!r} only applies to the pallas "
+                    f"backends, not {name!r}")
+            opts = {} if mode is None else {"mode": mode}
+            out = ir.get_backend(name).run(compiled, planes, **opts).planes
+        with jax.profiler.TraceAnnotation(UNPACK_SPAN):
+            results, i = [], 0
+            for t in self.out_types:
+                results.append(
+                    t.from_planes([out[i + j] for j in range(t.width)], n))
+                i += t.width
+            return results[0] if len(results) == 1 else tuple(results)
 
 
 def trace(fn, dtype) -> CompiledPimFunction:

@@ -328,3 +328,34 @@ def test_compress_schedule_deprecation_warns():
     direct = ir.lower(ir.from_schedule(sched)).to_schedule()
     assert np.array_equal(compressed.ops, direct.ops)
     assert compressed.num_cols == direct.num_cols
+
+
+def test_call_records_pack_execute_unpack_spans(tmp_path):
+    """One call under the profiler writes the spans ``pim.pack``,
+    ``pim.execute`` and ``pim.unpack`` once each, in that order, inside the
+    caller's own span; a repeated call re-runs no compiler pass."""
+    import jax
+
+    from repro.pim import frontend
+
+    fn = pim.compile(lambda a, b: a + b, dtype=pim.int8, backend="interpreter")
+    rng = np.random.default_rng(5)
+    x, y = (_rand(pim.int8, rng) for _ in range(2))
+    jax.block_until_ready(fn(x, y))
+    misses = ir.cache_stats()["misses"]
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("caller"):
+            jax.block_until_ready(fn(x, y))
+    assert ir.cache_stats()["misses"] == misses
+    [path] = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    names = ("caller", frontend.PACK_SPAN, frontend.EXECUTE_SPAN,
+             frontend.UNPACK_SPAN)
+    events = sorted((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+                    for plane in jax.profiler.ProfileData.from_file(
+                        str(path)).planes if plane.name.startswith("/host:")
+                    for line in plane.lines for ev in line.events
+                    if ev.name in names)
+    assert [name for _, _, name in events] == list(names)
+    (c0, c1, _), *spans = events
+    assert c0 <= spans[0][0] and spans[-1][1] <= c1
+    assert all(e <= s for (_, e, _), (s, _, _) in zip(spans, spans[1:]))

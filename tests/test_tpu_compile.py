@@ -10,6 +10,7 @@ xdist worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,9 +60,18 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_mosaic(lowered):
+def _assert_mosaic(lowered, kernel=None):
+    """The compile emitted a Mosaic kernel, named ``kernel`` if given: the
+    HLO instruction of the custom call takes the ``pallas_call``'s name,
+    which a profiler trace shows as the kernel's op name."""
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text
+    if kernel is not None:
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        assert calls
+        assert all(re.match(rf"\s*(ROOT )?%{kernel}(\.\d+)? = ", line)
+                   for line in calls), calls
 
 
 def _register(fn, dtype, basis):
@@ -78,7 +88,7 @@ def test_unrolled_segment_compiles(one_chip, dtype, basis):
     lowered = pim_bitserial._run_unrolled_segment.lower(
         state, schedule_key=key, gen=pim_bitserial._GENERATIONS.get(key, 0),
         seg=0, interpret=False)
-    _assert_mosaic(lowered)
+    _assert_mosaic(lowered, kernel="pim_segment_0")
 
 
 @pytest.mark.parametrize("op", ["f32_mac", "float_div"])
@@ -95,7 +105,7 @@ def test_loop_kernel_compiles(one_chip, op):
     lowered = pim_bitserial._run.lower(
         *gates, planes, schedule_key=key,
         gen=pim_bitserial._GENERATIONS.get(key, 0), interpret=False)
-    _assert_mosaic(lowered)
+    _assert_mosaic(lowered, kernel="pim_loop")
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
