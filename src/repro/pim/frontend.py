@@ -36,7 +36,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core import aritpim, ir
+from repro.core import aritpim, bitplanes, ir
 from repro.core.bitplanes import PimType
 
 # Profiler spans of one call (``CompiledPimFunction.__call__``), in order.
@@ -53,8 +53,8 @@ class TraceError(TypeError):
 def _encode_scalar(value, dtype: PimType) -> int:
     """A Python scalar's LSB-first bit pattern in ``dtype``'s plane layout.
 
-    Reuses the exact ``PimType`` pack path (cast + ``to_planes`` on a
-    one-element array), so constants round/wrap exactly like runtime data:
+    Reuses the exact ``PimType`` pack path (``to_planes`` on a one-element
+    array), so constants round/wrap exactly like runtime data:
     floats go through IEEE/bf16 rounding, fixed-point wraps two's-complement
     to ``nbits``.  Non-integral constants are rejected for fixed types."""
     if dtype.kind == "fixed":
@@ -77,8 +77,8 @@ def _encode_scalar(value, dtype: PimType) -> int:
         except OverflowError:
             raise TraceError(
                 f"constant {value!r} overflows {dtype.name}") from None
-    planes = dtype.to_planes(dtype.cast(jnp.asarray(value).reshape(1)))
-    return sum((int(p[0]) & 1) << k for k, p in enumerate(planes))
+    planes = dtype.to_planes(jnp.asarray(value).reshape(1))
+    return sum((int(p) & 1) << k for k, p in enumerate(planes[:, 0]))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -230,24 +230,23 @@ class CompiledPimFunction:
         """Pack ``arrays`` to bit-planes, run the compiled program, unpack.
 
         Each phase is a ``jax.profiler.TraceAnnotation`` span, so a
-        profiler trace shows where a call's host time goes:
-        ``PACK_SPAN`` (cast, ``to_planes``, stacking the input planes),
-        ``EXECUTE_SPAN`` (the compile-cache lookup and the backend's
-        ``run``: padding, placement, every kernel launch, the trim) and
-        ``UNPACK_SPAN`` (``from_planes`` and the result tuple).  Nothing
-        here waits for a result, so the spans time the host's enqueue of
-        device work (which stalls while the device's queue is full), not
-        the device; under a caller's ``jax.jit`` they time tracing.  With
-        no profiler running a span costs about a microsecond."""
+        profiler trace shows where a call's host time goes: ``PACK_SPAN``
+        encloses the enqueue of one program (``bitplanes.pack``: cast and
+        pack every input), ``EXECUTE_SPAN`` the compile-cache lookup and
+        the backend's ``run`` (padding, placement, every kernel launch, the
+        trim) and ``UNPACK_SPAN`` the enqueue of one program
+        (``bitplanes.unpack``: every result).  Nothing here waits for a
+        result, so the spans time the host's enqueue of device work (which
+        stalls while the device's queue is full), not the device; under a
+        caller's ``jax.jit`` the pack and unpack programs nest in the
+        caller's and the spans time tracing.  With no profiler running a
+        span costs about a microsecond."""
         if len(arrays) != len(self.in_types):
             raise TypeError(
                 f"expected {len(self.in_types)} arrays, got {len(arrays)}")
+        n = jnp.shape(arrays[0])[0]
         with jax.profiler.TraceAnnotation(PACK_SPAN):
-            arrays = [t.cast(x) for t, x in zip(self.in_types, arrays)]
-            n = arrays[0].shape[0]
-            planes = jnp.stack(
-                [p for t, x in zip(self.in_types, arrays)
-                 for p in t.to_planes(x)])
+            planes = bitplanes.pack(self.in_types, *arrays)
         with jax.profiler.TraceAnnotation(EXECUTE_SPAN):
             compiled = self.compiled(basis, passes)
             name = backend or self.backend
@@ -258,12 +257,8 @@ class CompiledPimFunction:
             opts = {} if mode is None else {"mode": mode}
             out = ir.get_backend(name).run(compiled, planes, **opts).planes
         with jax.profiler.TraceAnnotation(UNPACK_SPAN):
-            results, i = [], 0
-            for t in self.out_types:
-                results.append(
-                    t.from_planes([out[i + j] for j in range(t.width)], n))
-                i += t.width
-            return results[0] if len(results) == 1 else tuple(results)
+            results = bitplanes.unpack(self.out_types, out, n)
+        return results[0] if len(results) == 1 else results
 
 
 def trace(fn, dtype) -> CompiledPimFunction:

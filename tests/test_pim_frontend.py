@@ -359,3 +359,41 @@ def test_call_records_pack_execute_unpack_spans(tmp_path):
     (c0, c1, _), *spans = events
     assert c0 <= spans[0][0] and spans[-1][1] <= c1
     assert all(e <= s for (_, e, _), (s, _, _) in zip(spans, spans[1:]))
+
+
+_TRACE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                 "/jax/core/compile/backend_compile_duration")
+
+
+@pytest.mark.parametrize("dtype,max_eqns", [("f32", 6), ("int8", 16)])
+def test_call_is_a_few_programs(dtype, max_eqns):
+    """One call is a handful of top-level equations, pack and unpack one
+    jitted program each: the f32 MAC runs the loop kernel (pack, pad,
+    kernel, trim, unpack); the int8 MAC runs the unrolled kernel, whose
+    placement (zero-fill, scatter, gather and their index arithmetic) is
+    still eager.  A repeated call with the same shapes traces and compiles
+    nothing."""
+    import jax
+    from jax._src import monitoring
+
+    fn = pim.compile(_MAC, dtype=_DTYPES[dtype])
+    rng = np.random.default_rng(11)
+    args = [_rand(_DTYPES[dtype], rng) for _ in range(3)]
+    eqns = jax.make_jaxpr(fn)(*args).jaxpr.eqns
+    jitted = [e.params["name"] for e in eqns if e.primitive.name == "jit"]
+    assert jitted.count("pack") == 1 and jitted.count("unpack") == 1
+    assert len(eqns) <= max_eqns, [e.primitive.name for e in eqns]
+
+    jax.block_until_ready(fn(*args))
+    events = []
+
+    def listener(event, duration, **kwargs):
+        if event in _TRACE_EVENTS:
+            events.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        _check(_DTYPES[dtype], fn(*args), _oracle(_MAC, _DTYPES[dtype], args))
+    finally:
+        monitoring.unregister_event_duration_listener(listener)
+    assert events == []

@@ -114,3 +114,20 @@ def test_matmul_compiles(one_chip, dtype):
     a = _sds((2, 128, 128), dtype, one_chip)
     b = _sds((2, 128, 128), dtype, one_chip)
     _assert_mosaic(pim_matmul.matmul.lower(a, b, interpret=False))
+
+
+@pytest.mark.parametrize("dtype", [pim.f32, pim.bf16, pim.int8],
+                         ids=lambda t: t.name)
+def test_pack_unpack_compile(one_chip, dtype):
+    """Pack and unpack compile as plain XLA programs: no Mosaic kernel,
+    which a trace would count as executor kernel time."""
+    from repro.core import bitplanes
+
+    carrier = {"float32": jnp.float32, "bf16": jnp.bfloat16}.get(
+        dtype.kind, jnp.int32)
+    n = 32 * W
+    x = _sds((n,), carrier, one_chip)
+    planes = _sds((3 * dtype.width, W), jnp.uint32, one_chip)
+    for lowered in (bitplanes.pack.lower((dtype,) * 3, x, x, x),
+                    bitplanes.unpack.lower((dtype,) * 3, planes, n)):
+        assert "tpu_custom_call" not in lowered.compile().as_text()
