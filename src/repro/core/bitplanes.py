@@ -161,6 +161,43 @@ def unpack(types: tuple[PimType, ...], planes: jnp.ndarray,
     return tuple(out)
 
 
+def _padded_cols(n: int) -> int:
+    """N padded to whole words: a contraction's output row fills whole words."""
+    return num_words(n) * WORD
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def pack_contraction(t: PimType, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """The step operand planes of ``a [M, K] @ b [K, N]``:
+    ``[K, 2 * width, M * N' / 32]``, step ``k``'s ``a_k`` planes then its
+    ``b_k`` planes.
+
+    Output element ``e = m * N' + n``, with N padded to ``N'``, a multiple
+    of 32 (zero columns of ``b``), so one word holds 32 consecutive ``n`` of
+    one ``m``.  ``a_k[e] = a[m, k]``: each word of plane ``j`` is
+    ``0 - bit_j(a[m, k])``, all ones or all zeros.  ``b_k[e] = b[k, n]``:
+    the planes of row ``k`` of ``b`` (:func:`words_to_planes`), tiled over
+    ``m``."""
+    m = a.shape[0]
+    n_pad = _padded_cols(b.shape[1])
+    j = jnp.arange(t.width, dtype=jnp.uint32)[:, None]
+    a_bits = (t.to_words(a).T[:, None, :] >> j) & 1              # [K, w, M]
+    a_planes = jnp.repeat(jnp.uint32(0) - a_bits, n_pad // WORD, axis=2)
+    ub = jnp.pad(t.to_words(b), ((0, 0), (0, n_pad - b.shape[1])))
+    b_planes = jax.vmap(words_to_planes, in_axes=(0, None))(ub, t.width)
+    return jnp.concatenate([a_planes, jnp.tile(b_planes, (1, 1, m))], axis=1)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2, 3))
+def unpack_contraction(t: PimType, planes: jnp.ndarray, m: int,
+                       n: int) -> jnp.ndarray:
+    """Inverse layout of :func:`pack_contraction`'s output elements:
+    ``[width, M * N' / 32]`` planes → ``[M, N]`` (the padding trimmed)."""
+    n_pad = _padded_cols(n)
+    u = planes_to_words(planes, m * n_pad).reshape(m, n_pad)[:, :n]
+    return t.from_words(u)
+
+
 # ---------------------------------------------------------------------------
 # Per-plane list views (aritpim's PlaneVM and tests hold planes as lists)
 # ---------------------------------------------------------------------------

@@ -951,6 +951,41 @@ class Program:
         )
 
 
+@dataclasses.dataclass(frozen=True)
+class Contraction:
+    """A program run K times with one value carried from step to step.
+
+    ``step`` is an ordinary :class:`Program`.  Its input ``carry_in`` is the
+    accumulator: +0 before step 0, then the previous step's output
+    ``carry_out``.  Its other inputs are the step's operands, fresh at every
+    step.  Only the last step's ``carry_out`` leaves the array.  ``a @ b``
+    is the fused MAC ``a * b + c`` with ``carry_in=2`` (``c``) and
+    ``carry_out=0``: ``acc = fl(fl(a_k * b_k) + acc)`` for k in order.
+
+    The step compiles like any program (``compile_program`` and
+    ``program_cost`` accept a contraction and return the step's schedule
+    and per-step cost), so it shares the step program's cache entry."""
+
+    step: Program
+    carry_in: int
+    carry_out: int = 0
+
+    def __post_init__(self):
+        if len(self.step.outputs) != 1 or self.carry_out != 0:
+            raise ValueError("a contraction's step has one output, the carry")
+
+    def slots(self, compiled: "CompiledSchedule"
+              ) -> tuple[tuple[int, ...], tuple[int, ...], tuple[int, ...]]:
+        """Columns of the step's schedule: the operand inputs (sorted-name
+        order, the carried input left out), the carried input, the output."""
+        carried = self.step.input_names()[self.carry_in]
+        operands = tuple(c for name in sorted(compiled.input_cols)
+                         if name != carried for c in compiled.input_cols[name])
+        out = self.step.output_names()[self.carry_out]
+        return (operands, tuple(compiled.input_cols[carried]),
+                tuple(compiled.output_cols[out]))
+
+
 def record_program(program: Program) -> ScheduleIR:
     """Record a multi-op program into one SSA IR (NOR basis): per-op
     netlists are stitched value-to-value in a single ``PlaneVM``, so the
@@ -1012,16 +1047,18 @@ def cache_stats() -> dict[str, int]:
 
 
 def compile_program(
-    program: Program,
+    program: Program | Contraction,
     passes: tuple[str, ...] = DEFAULT_PASSES,
     basis: str | LogicBasis = "memristive",
 ) -> CompiledSchedule:
     """Record → basis-lower → optimize → allocate a multi-op program, cached
-    by ``(program, basis, pass_list)``.
+    by ``(program, basis, pass_list)``.  A contraction compiles its step.
 
     The column-budget baseline is the *basis-lowered* program allocated with
     no optimization passes, so the CSE window ladder compares like with like
     on both bases."""
+    if isinstance(program, Contraction):
+        program = program.step
     basis = get_basis(basis)
     passes = tuple(passes)
     cache_key = (program.key, basis.name, passes)
@@ -1118,6 +1155,15 @@ class Backend:
     def run(self, compiled: CompiledSchedule, planes: jnp.ndarray | None = None,
             **opts: Any) -> ExecutionResult:
         raise NotImplementedError
+
+    def contract(self, compiled: CompiledSchedule, contraction: Contraction,
+                 steps: jnp.ndarray) -> jnp.ndarray:
+        """Run ``contraction``, whose step compiled to ``compiled``, once per
+        step: ``steps`` is ``[K, operand planes, W]`` (operands in
+        sorted-name order).  Returns the carried output's ``[width, W]``
+        planes after the last step."""
+        raise ValueError(f"the {self.name!r} backend runs no contraction; "
+                         "use 'pallas'")
 
     def cost(self, compiled: CompiledSchedule,
              cycles_per_gate: int | None = None) -> CostReport:
@@ -1224,7 +1270,7 @@ def op_cost(op: str, nbits: int = 32,
     return get_backend("cost").run(compile_op(op, nbits, passes, basis)).cost
 
 
-def program_cost(program: Program,
+def program_cost(program: Program | Contraction,
                  passes: tuple[str, ...] = DEFAULT_PASSES,
                  basis: str | LogicBasis = "memristive") -> CostReport:
     """Program-level analytical cost (the multi-op analogue of ``op_cost``)."""

@@ -31,6 +31,11 @@ Two executor modes share the registry (DESIGN.md §5):
   ``pl.pallas_call`` with the grid over word-blocks and the state block
   aliased in/out.
 
+A third kernel, ``pim_contract``, runs a contraction (``a @ b``, an
+``ir.Contraction``): the step schedule K times per word-block, with the
+crossbar state and the carried accumulator in VMEM across the steps and
+only each step's operand planes read from HBM (DESIGN.md §5).
+
 Pallas runs in interpret mode exactly when the input planes live on the CPU
 (``repro.kernels.interpret_mode``), so tests on the CPU trace the same kernel
 bodies that the TPU compiles.
@@ -90,6 +95,8 @@ UNROLL_SEGMENT_GATES = 1024
 # and ``<SEGMENT_KERNEL>_<k>`` for the k-th unrolled segment.
 LOOP_KERNEL = "pim_loop"
 SEGMENT_KERNEL = "pim_segment"
+# The contraction kernel (``a @ b``): the step schedule run K times.
+CONTRACT_KERNEL = "pim_contract"
 
 
 # ---------------------------------------------------------------------------
@@ -97,12 +104,9 @@ SEGMENT_KERNEL = "pim_segment"
 # ---------------------------------------------------------------------------
 
 
-def _kernel(op_ref, a_ref, b_ref, c_ref, o_ref, in_ref, out_ref, state, *,
-            input_slots, output_slots):
-    # Load this block's input planes into their crossbar columns (static slots).
-    for i, col in enumerate(input_slots):
-        state[col, :] = in_ref[i, :]
-
+def _gate_loop(op_ref, a_ref, b_ref, c_ref, o_ref, state):
+    """Run the schedule's gates over ``state``, one per iteration (the body
+    ``pim_loop`` and ``pim_contract`` share)."""
     n_gates = op_ref.shape[0]
 
     def body(g, _):
@@ -130,6 +134,13 @@ def _kernel(op_ref, a_ref, b_ref, c_ref, o_ref, in_ref, out_ref, state, *,
 
     jax.lax.fori_loop(0, n_gates, body, 0)
 
+
+def _kernel(op_ref, a_ref, b_ref, c_ref, o_ref, in_ref, out_ref, state, *,
+            input_slots, output_slots):
+    # Load this block's input planes into their crossbar columns (static slots).
+    for i, col in enumerate(input_slots):
+        state[col, :] = in_ref[i, :]
+    _gate_loop(op_ref, a_ref, b_ref, c_ref, o_ref, state)
     for i, col in enumerate(output_slots):
         out_ref[i, :] = state[col, :]
 
@@ -158,6 +169,87 @@ def _run(op, a, b, c, o, planes, *, schedule_key, gen, interpret):
         interpret=interpret,
         name=LOOP_KERNEL,
     )(op, a, b, c, o, planes)
+
+
+# ---------------------------------------------------------------------------
+# Contraction kernel: the step schedule run K times per word-block
+# ---------------------------------------------------------------------------
+
+
+def _contract_kernel(op_ref, a_ref, b_ref, c_ref, o_ref, steps_hbm, out_ref,
+                     state, carry, buf, sem, *, operand_slots, acc_slots,
+                     output_slots):
+    # Grid (word-block i, step k), k innermost: the crossbar state and the
+    # carried result stay in VMEM over all K steps of a block.  Only each
+    # step's operand planes come in from HBM, by a DMA started one step
+    # ahead (so it overlaps the previous step's gates), and the result goes
+    # out after the last step.
+    i, k = pl.program_id(0), pl.program_id(1)
+
+    def fetch(step):
+        return pltpu.make_async_copy(
+            steps_hbm.at[step, :, pl.ds(i * BLOCK_WORDS, BLOCK_WORDS)],
+            buf, sem)
+
+    @pl.when(k == 0)
+    def _():
+        fetch(0).start()
+        for col in acc_slots:
+            state[col, :] = jnp.zeros((BLOCK_WORDS,), jnp.uint32)
+
+    @pl.when(k > 0)
+    def _():
+        for j, col in enumerate(acc_slots):
+            state[col, :] = carry[j, :]
+
+    fetch(k).wait()
+    for n, col in enumerate(operand_slots):
+        state[col, :] = buf[n, :]
+
+    @pl.when(k + 1 < pl.num_programs(1))
+    def _():
+        fetch(k + 1).start()
+
+    _gate_loop(op_ref, a_ref, b_ref, c_ref, o_ref, state)
+    # The output columns overlap the accumulator's and the operands', so the
+    # result is set aside before the next step loads its inputs.
+    for j, col in enumerate(output_slots):
+        carry[j, :] = state[col, :]
+
+    @pl.when(k == pl.num_programs(1) - 1)
+    def _():
+        out_ref[...] = carry[...]
+
+
+@functools.partial(jax.jit, static_argnames=("num_cols", "slots", "interpret"))
+def _run_contract(op, a, b, c, o, steps, *, num_cols, slots, interpret):
+    """``steps`` ``[K, operand planes, W]`` → the carry's ``[width, W]``
+    planes; W is padded to a BLOCK_WORDS multiple and trimmed here.
+    ``slots`` is ``Contraction.slots`` of the schedule."""
+    operand_slots, acc_slots, output_slots = slots
+    n_steps, n_in, W = steps.shape
+    pad = (-W) % BLOCK_WORDS
+    if pad:
+        steps = jnp.pad(steps, ((0, 0), (0, 0), (0, pad)))
+    n_out = len(output_slots)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    out = pl.pallas_call(
+        functools.partial(_contract_kernel, operand_slots=operand_slots,
+                          acc_slots=acc_slots, output_slots=output_slots),
+        grid=((W + pad) // BLOCK_WORDS, n_steps),
+        in_specs=[smem] * 5 + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((n_out, BLOCK_WORDS), lambda i, k: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((n_out, W + pad), jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((num_cols, BLOCK_WORDS), jnp.uint32),
+                        pltpu.VMEM((n_out, BLOCK_WORDS), jnp.uint32),
+                        pltpu.VMEM((n_in, BLOCK_WORDS), jnp.uint32),
+                        pltpu.SemaphoreType.DMA(())],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=CONTRACT_KERNEL,
+    )(op, a, b, c, o, steps)
+    return out[:, :W]
 
 
 # ---------------------------------------------------------------------------
@@ -405,6 +497,17 @@ class PallasBackend(ir.Backend):
         key = register_compiled(compiled)
         out = run_schedule(key, planes, mode=mode or self.mode)
         return ir.ExecutionResult(out, self.cost(compiled))
+
+    def contract(self, compiled, contraction, steps):
+        """One ``pim_contract`` launch, whatever K is (the unrolled kernel
+        has no contraction form)."""
+        if self.mode == "unrolled":
+            super().contract(compiled, contraction, steps)
+        key = register_compiled(compiled)
+        return _run_contract(*_gate_arrays(key), steps,
+                             num_cols=compiled.num_cols,
+                             slots=contraction.slots(compiled),
+                             interpret=interpret_mode(steps))
 
 
 class PallasUnrolledBackend(PallasBackend):

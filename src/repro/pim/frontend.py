@@ -21,6 +21,11 @@ picked by the tracer's :class:`~repro.core.bitplanes.PimType` via the
 (``ir.CONST_OP``) — they cost no HBM input traffic and constant folding
 sees straight through them.
 
+``a @ b`` over the function's two arguments traces a contraction
+(``ir.Contraction``): K steps of the fused MAC ``a * b + c`` with the
+accumulator carried, run by one ``pim_contract`` kernel (see
+:meth:`CompiledPimFunction._contract`).
+
 A single-op trace canonicalizes to ``ir.Program.single``, so e.g.
 ``pim.compile(lambda a, b: a + b, dtype=pim.f32)`` shares its compile-cache
 entry with ``ir.compile_op("float_add")`` and every legacy wrapper.
@@ -101,6 +106,10 @@ class Tracer:
             )
         if other.trace is not self.trace:
             raise TraceError("tracers from different traces cannot be combined")
+        if self.trace.contraction is not None:
+            raise TraceError(
+                "a @ b must be the traced program's whole body: no op may "
+                "follow it or use its result (epilogues are not supported)")
         if other.dtype != self.dtype:
             raise TraceError(
                 f"dtype mismatch in {arith!r}: {self.dtype.name} vs "
@@ -133,6 +142,9 @@ class Tracer:
     def __rtruediv__(self, other):
         return self._bin(other, "div", reverse=True)
 
+    def __matmul__(self, other):
+        return self.trace.contract(self, other)
+
 
 class Trace:
     """Accumulates the op graph while the traced function runs."""
@@ -142,6 +154,7 @@ class Trace:
         self.body: list[ir.ProgramOp] = []
         self._next_id = 0
         self._consts: dict[tuple[int, str], Tracer] = {}
+        self.contraction: Tracer | None = None  # the result of ``a @ b``
 
     def _fresh(self) -> int:
         v = self._next_id
@@ -169,6 +182,25 @@ class Trace:
         tracer = Tracer(self, out, dtype)
         self._consts[key] = tracer
         return tracer
+
+    def contract(self, a, b) -> Tracer:
+        """``a @ b`` over the function's two inputs, in order: the whole
+        program (see :meth:`CompiledPimFunction._contract`)."""
+        if not (isinstance(a, Tracer) and isinstance(b, Tracer)
+                and a.trace is self and b.trace is self):
+            raise TraceError("a @ b contracts two tracers of one trace")
+        if self.contraction is not None:
+            raise TraceError("a program holds at most one contraction")
+        if a.dtype != b.dtype:
+            raise TraceError(
+                f"dtype mismatch in '@': {a.dtype.name} vs {b.dtype.name} "
+                "(no implicit promotion)")
+        if self.body or len(self.in_types) != 2 or (a.id, b.id) != (0, 1):
+            raise TraceError(
+                "a @ b must be the traced program's whole body, over the "
+                "function's two arguments in order: lambda a, b: a @ b")
+        self.contraction = Tracer(self, self._fresh(), a.dtype)
+        return self.contraction
 
     def emit(self, arith: str, a: Tracer, b: Tracer) -> Tracer:
         op = aritpim.op_for(arith, a.dtype.kind)
@@ -210,18 +242,21 @@ class CompiledPimFunction:
     the ``ir`` compile cache, so constructing one (e.g. at module import in
     ``kernels.ops``) costs only the trace."""
 
-    program: ir.Program
+    program: ir.Program | ir.Contraction
     in_types: tuple[PimType, ...]
     out_types: tuple[PimType, ...]
     backend: str = "pallas"
 
     def compiled(self, basis: str = "memristive",
                  passes: tuple[str, ...] = ir.DEFAULT_PASSES) -> ir.CompiledSchedule:
+        """The compiled schedule; for ``a @ b``, the schedule of one MAC
+        step, which a dispatch runs K times."""
         return ir.compile_program(self.program, passes, basis)
 
     def cost(self, basis: str = "memristive",
              passes: tuple[str, ...] = ir.DEFAULT_PASSES) -> ir.CostReport:
-        """Program-level CostReport from the analytical backend."""
+        """Program-level CostReport from the analytical backend; for
+        ``a @ b``, the cost of one MAC step, which a dispatch runs K times."""
         return ir.program_cost(self.program, passes, basis)
 
     def __call__(self, *arrays, basis: str = "memristive",
@@ -244,6 +279,9 @@ class CompiledPimFunction:
         if len(arrays) != len(self.in_types):
             raise TypeError(
                 f"expected {len(self.in_types)} arrays, got {len(arrays)}")
+        if isinstance(self.program, ir.Contraction):
+            return self._contract(*arrays, basis=basis, passes=passes,
+                                  backend=backend, mode=mode)
         n = jnp.shape(arrays[0])[0]
         with jax.profiler.TraceAnnotation(PACK_SPAN):
             planes = bitplanes.pack(self.in_types, *arrays)
@@ -259,6 +297,38 @@ class CompiledPimFunction:
         with jax.profiler.TraceAnnotation(UNPACK_SPAN):
             results = bitplanes.unpack(self.out_types, out, n)
         return results[0] if len(results) == 1 else results
+
+    def _contract(self, a, b, *, basis, passes, backend, mode):
+        """``a [M, K] @ b [K, N]`` → ``[M, N]`` in the input dtype: a serial
+        MAC contraction with a fixed rounding order, not a tree reduction.
+        ``acc = +0``, then for ``k = 0 .. K-1`` in order
+        ``acc = fl(fl(a[m, k] * b[k, n]) + acc)``, each step rounded as the
+        program ``a * b + c`` rounds (nearest-even, subnormals kept, for
+        floats; two's-complement wrap for fixed types).
+
+        The same spans as an element-wise call: ``PACK_SPAN`` encloses one
+        program (``bitplanes.pack_contraction``: the ``[K, 2 * width, W]``
+        step operand planes), ``EXECUTE_SPAN`` the cache lookup and one
+        ``pim_contract`` launch, ``UNPACK_SPAN`` one program
+        (``bitplanes.unpack_contraction``).  Any K is three programs."""
+        shapes = (jnp.shape(a), jnp.shape(b))
+        if (len(shapes[0]) != 2 or len(shapes[1]) != 2
+                or shapes[0][1] != shapes[1][0] or 0 in shapes[0] + shapes[1]):
+            raise ValueError(
+                f"a @ b takes a [M, K] and b [K, N] with M, K, N >= 1, "
+                f"got shapes {shapes[0]} and {shapes[1]}")
+        if mode is not None:
+            raise ValueError(f"a contraction has one kernel, no mode {mode!r}")
+        t = self.in_types[0]
+        with jax.profiler.TraceAnnotation(PACK_SPAN):
+            steps = bitplanes.pack_contraction(t, a, b)
+        with jax.profiler.TraceAnnotation(EXECUTE_SPAN):
+            compiled = self.compiled(basis, passes)
+            out = ir.get_backend(backend or self.backend).contract(
+                compiled, self.program, steps)
+        with jax.profiler.TraceAnnotation(UNPACK_SPAN):
+            return bitplanes.unpack_contraction(t, out, shapes[0][0],
+                                                shapes[1][1])
 
 
 def trace(fn, dtype) -> CompiledPimFunction:
@@ -287,6 +357,15 @@ def trace(fn, dtype) -> CompiledPimFunction:
     if not outs or not all(isinstance(o, Tracer) and o.trace is t for o in outs):
         raise TraceError("the traced function must return its tracer value(s)")
     name = re.sub(r"[^A-Za-z0-9_]", "", getattr(fn, "__name__", "")) or "program"
+    if t.contraction is not None:
+        if tuple(outs) != (t.contraction,):
+            raise TraceError("a program with a @ b returns a @ b alone")
+        # The step is the fused MAC, traced as ``a * b + c`` is, so it shares
+        # that program's compile-cache entry and schedule; ``c`` is carried.
+        step = trace(lambda a, b, c: a * b + c, dtypes[0]).program
+        return CompiledPimFunction(
+            program=ir.Contraction(step, carry_in=2, carry_out=0),
+            in_types=dtypes, out_types=(dtypes[0],))
     program = _canonical_program(t, outs, name)
     return CompiledPimFunction(
         program=program,
@@ -296,7 +375,8 @@ def trace(fn, dtype) -> CompiledPimFunction:
 
 
 def compile(fn, dtype, backend: str = "pallas") -> CompiledPimFunction:  # noqa: A001
-    """Trace-and-compile an element-wise PIM program (the public API).
+    """Trace-and-compile an element-wise PIM program, or the contraction
+    ``a @ b`` (the public API).
 
     ``dtype`` is one :class:`PimType` for all arguments or a sequence of
     per-argument types (both operands of every op must agree — there is no

@@ -108,6 +108,20 @@ def test_loop_kernel_compiles(one_chip, op):
     _assert_mosaic(lowered, kernel="pim_loop")
 
 
+@pytest.mark.parametrize("basis", ["memristive", "dram"])
+def test_contract_kernel_compiles(one_chip, basis):
+    """``pim_contract`` runs the f32 MAC step K = 3 times per word-block,
+    its step planes read from HBM by DMA."""
+    fn = pim.compile(lambda a, b: a @ b, dtype=pim.f32)
+    compiled = fn.compiled(basis)
+    gates = [_sds((compiled.num_gates,), jnp.int32, one_chip)] * 5
+    steps = _sds((3, 64, W), jnp.uint32, one_chip)
+    lowered = pim_bitserial._run_contract.lower(
+        *gates, steps, num_cols=compiled.num_cols,
+        slots=fn.program.slots(compiled), interpret=False)
+    _assert_mosaic(lowered, kernel="pim_contract")
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 def test_matmul_compiles(one_chip, dtype):
