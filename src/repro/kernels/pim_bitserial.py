@@ -13,7 +13,7 @@ in-memory property the paper models.
 Two executor modes share the registry (DESIGN.md §5):
 
 * ``loop`` — the original ``fori_loop`` kernel: one gate per iteration,
-  a dynamic single-row read and write of the state plus a five-deep
+  a dynamic read and write of whole column tiles plus a five-deep
   ``jnp.where`` opcode select, with the five gate arrays held in SMEM (5
   int32 words per gate; v5e's 1 MiB of SMEM holds schedules of up to about
   50k gates, and the TPU compiler refuses longer ones).  O(1) compile in
@@ -50,11 +50,18 @@ kernel — are cached by schedule key, so repeat dispatches stop rebuilding
 and re-transferring them.
 
 Tiling: the grid runs over blocks of the packed-words axis; each program
-holds the *entire* (column-allocated) crossbar state for its word-block in
-``[num_cols, BLOCK_WORDS]`` — with ``num_cols ≤ 133`` for float ops (see
-``ir.lower`` and the ``reorder`` pass) and ``BLOCK_WORDS = 256`` that is a
-~136 KiB working set, comfortably inside VMEM and an exact analogue of one
-crossbar's 1024-column budget.
+holds the *entire* (column-allocated) crossbar state for its word-block.
+The loop and contraction kernels keep column ``col`` as a full-vreg tile
+``state[col]`` of ``(8, L)`` words, ``L = 128·u``: a word-block is
+``1024·u`` words, and a gate reads and writes whole vregs at a dynamic
+leading-axis offset (no sublane shift, no masked store).  ``u`` is chosen
+per call from the padded word count and the planes held in VMEM
+(``loop_block_units``): the fewest blocks whose footprint fits
+``LOOP_VMEM_BYTES``, then the least padding — 8 for the f32 MAC's 160
+columns at 6.4M elements, 1 block of 4 for the ResNet-50 conv's 3,136
+words.  The planes move between ``[n, W]`` in HBM and ``(8, L)`` tiles by
+an XLA pad and reshape inside the kernel's jitted program.  ``BLOCK_WORDS = 256``
+is the unrolled segment kernel's block alone: ``[num_cols, 256]`` rows.
 """
 
 from __future__ import annotations
@@ -78,7 +85,15 @@ from repro.core.machine import (
 )
 from repro.kernels import interpret_mode
 
+# Word-block of the unrolled segment kernel: ``[num_cols, BLOCK_WORDS]``.
 BLOCK_WORDS = 256
+# The loop and contraction kernels' tiles: a column's words of one block are
+# ``(SUBLANES, LANES·u)``, ``u`` full vregs of ``TILE_WORDS`` words.
+SUBLANES, LANES = 8, 128
+TILE_WORDS = SUBLANES * LANES
+# VMEM the loop kernels' planes may take: under v5e's 16 MiB scoped default,
+# with room for what Mosaic keeps besides.
+LOOP_VMEM_BYTES = 14 * 2**20
 UMAX32 = 0xFFFFFFFF  # python int: folded into the kernel, not a captured array
 
 # Mode-auto threshold: schedules at or below this many gates unroll; longer
@@ -115,9 +130,9 @@ def _gate_loop(op_ref, a_ref, b_ref, c_ref, o_ref, state):
         b = b_ref[g]
         c = c_ref[g]
         o = o_ref[g]
-        va = state[pl.ds(a, 1), :]
-        vb = state[pl.ds(b, 1), :]
-        vc = state[pl.ds(c, 1), :]
+        va = state[a]
+        vb = state[b]
+        vc = state[c]
         nor = ~(va | vb)
         maj = (va & vb) | (va & vc) | (vb & vc)
         res = jnp.where(
@@ -129,24 +144,49 @@ def _gate_loop(op_ref, a_ref, b_ref, c_ref, o_ref, state):
                                                     jnp.full_like(nor, UMAX32),
                                                     va)))),
         )
-        state[pl.ds(o, 1), :] = res
+        state[o] = res
         return 0
 
     jax.lax.fori_loop(0, n_gates, body, 0)
+
+
+def loop_block_units(words: int, planes: int) -> int:
+    """``u`` of a loop-kernel word-block of ``TILE_WORDS·u`` words over
+    ``words`` packed words, ``planes`` ``uint32`` planes per word held in
+    VMEM: the fewest blocks whose planes fit ``LOOP_VMEM_BYTES``, then the
+    least padding."""
+    units = -(-words // TILE_WORDS)
+    u_max = max(1, LOOP_VMEM_BYTES // (4 * TILE_WORDS * planes))
+    blocks = -(-units // u_max)
+    return -(-units // blocks)
+
+
+def _to_tiles(planes, units):
+    """``[..., W]`` planes → ``[..., rows, LANES·units]``, W padded to whole
+    word-blocks of ``SUBLANES`` rows; block ``i`` is rows
+    ``[SUBLANES·i, SUBLANES·(i + 1))``."""
+    W = planes.shape[-1]
+    block = TILE_WORDS * units
+    pad = (-W) % block
+    planes = jnp.pad(planes, [(0, 0)] * (planes.ndim - 1) + [(0, pad)])
+    return planes.reshape(*planes.shape[:-1], (W + pad) // (LANES * units),
+                          LANES * units)
 
 
 def _kernel(op_ref, a_ref, b_ref, c_ref, o_ref, in_ref, out_ref, state, *,
             input_slots, output_slots):
     # Load this block's input planes into their crossbar columns (static slots).
     for i, col in enumerate(input_slots):
-        state[col, :] = in_ref[i, :]
+        state[col] = in_ref[i]
     _gate_loop(op_ref, a_ref, b_ref, c_ref, o_ref, state)
     for i, col in enumerate(output_slots):
-        out_ref[i, :] = state[col, :]
+        out_ref[i] = state[col]
 
 
 @functools.partial(jax.jit, static_argnames=("schedule_key", "gen", "interpret"))
 def _run(op, a, b, c, o, planes, *, schedule_key, gen, interpret):
+    """``planes`` ``[n_in, W]`` → ``[n_out, W]``, run over word-blocks of
+    ``loop_block_units`` full-vreg tiles per column."""
     # `gen` bumps when a different schedule is registered under this key, so
     # traces that baked the old static slot maps are never reused.
     compiled = _SCHEDULES[schedule_key]
@@ -154,21 +194,25 @@ def _run(op, a, b, c, o, planes, *, schedule_key, gen, interpret):
     output_slots = compiled.output_slots
     n_in, W = planes.shape
     n_out = len(output_slots)
+    units = loop_block_units(W, compiled.num_cols + 2 * (n_in + n_out))
+    tiles = _to_tiles(planes, units)
+    rows, L = tiles.shape[1:]
     # The gate arrays are read one scalar per iteration at a dynamic index,
     # which Mosaic allows from SMEM but not from VMEM vectors.
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, input_slots=tuple(input_slots), output_slots=tuple(output_slots)),
-        grid=(W // BLOCK_WORDS,),
+        grid=(rows // SUBLANES,),
         in_specs=[smem] * 5 + [
-            pl.BlockSpec((n_in, BLOCK_WORDS), lambda i: (0, i)),
+            pl.BlockSpec((n_in, SUBLANES, L), lambda i: (0, i, 0)),
         ],
-        out_specs=pl.BlockSpec((n_out, BLOCK_WORDS), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n_out, W), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((compiled.num_cols, BLOCK_WORDS), jnp.uint32)],
+        out_specs=pl.BlockSpec((n_out, SUBLANES, L), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_out, rows, L), jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((compiled.num_cols, SUBLANES, L), jnp.uint32)],
         interpret=interpret,
         name=LOOP_KERNEL,
-    )(op, a, b, c, o, planes)
+    )(op, a, b, c, o, tiles)
+    return out.reshape(n_out, rows * L)[:, :W]
 
 
 # ---------------------------------------------------------------------------
@@ -188,23 +232,22 @@ def _contract_kernel(op_ref, a_ref, b_ref, c_ref, o_ref, steps_hbm, out_ref,
 
     def fetch(step):
         return pltpu.make_async_copy(
-            steps_hbm.at[step, :, pl.ds(i * BLOCK_WORDS, BLOCK_WORDS)],
-            buf, sem)
+            steps_hbm.at[step, :, pl.ds(i * SUBLANES, SUBLANES)], buf, sem)
 
     @pl.when(k == 0)
     def _():
         fetch(0).start()
         for col in acc_slots:
-            state[col, :] = jnp.zeros((BLOCK_WORDS,), jnp.uint32)
+            state[col] = jnp.zeros(buf.shape[1:], jnp.uint32)
 
     @pl.when(k > 0)
     def _():
         for j, col in enumerate(acc_slots):
-            state[col, :] = carry[j, :]
+            state[col] = carry[j]
 
     fetch(k).wait()
     for n, col in enumerate(operand_slots):
-        state[col, :] = buf[n, :]
+        state[col] = buf[n]
 
     @pl.when(k + 1 < pl.num_programs(1))
     def _():
@@ -214,7 +257,7 @@ def _contract_kernel(op_ref, a_ref, b_ref, c_ref, o_ref, steps_hbm, out_ref,
     # The output columns overlap the accumulator's and the operands', so the
     # result is set aside before the next step loads its inputs.
     for j, col in enumerate(output_slots):
-        carry[j, :] = state[col, :]
+        carry[j] = state[col]
 
     @pl.when(k == pl.num_programs(1) - 1)
     def _():
@@ -224,32 +267,34 @@ def _contract_kernel(op_ref, a_ref, b_ref, c_ref, o_ref, steps_hbm, out_ref,
 @functools.partial(jax.jit, static_argnames=("num_cols", "slots", "interpret"))
 def _run_contract(op, a, b, c, o, steps, *, num_cols, slots, interpret):
     """``steps`` ``[K, operand planes, W]`` → the carry's ``[width, W]``
-    planes; W is padded to a BLOCK_WORDS multiple and trimmed here.
-    ``slots`` is ``Contraction.slots`` of the schedule."""
+    planes, run over word-blocks of ``loop_block_units`` full-vreg tiles per
+    column.  ``slots`` is ``Contraction.slots`` of the schedule."""
     operand_slots, acc_slots, output_slots = slots
     n_steps, n_in, W = steps.shape
-    pad = (-W) % BLOCK_WORDS
-    if pad:
-        steps = jnp.pad(steps, ((0, 0), (0, 0), (0, pad)))
     n_out = len(output_slots)
+    # VMEM: the state, the carry, one step's operands, the output block
+    # double-buffered.
+    units = loop_block_units(W, num_cols + n_in + 3 * n_out)
+    tiles = _to_tiles(steps, units)
+    rows, L = tiles.shape[2:]
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         functools.partial(_contract_kernel, operand_slots=operand_slots,
                           acc_slots=acc_slots, output_slots=output_slots),
-        grid=((W + pad) // BLOCK_WORDS, n_steps),
+        grid=(rows // SUBLANES, n_steps),
         in_specs=[smem] * 5 + [pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((n_out, BLOCK_WORDS), lambda i, k: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n_out, W + pad), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((num_cols, BLOCK_WORDS), jnp.uint32),
-                        pltpu.VMEM((n_out, BLOCK_WORDS), jnp.uint32),
-                        pltpu.VMEM((n_in, BLOCK_WORDS), jnp.uint32),
+        out_specs=pl.BlockSpec((n_out, SUBLANES, L), lambda i, k: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_out, rows, L), jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((num_cols, SUBLANES, L), jnp.uint32),
+                        pltpu.VMEM((n_out, SUBLANES, L), jnp.uint32),
+                        pltpu.VMEM((n_in, SUBLANES, L), jnp.uint32),
                         pltpu.SemaphoreType.DMA(())],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=CONTRACT_KERNEL,
-    )(op, a, b, c, o, steps)
-    return out[:, :W]
+    )(op, a, b, c, o, tiles)
+    return out.reshape(n_out, rows * L)[:, :W]
 
 
 # ---------------------------------------------------------------------------
@@ -455,7 +500,9 @@ def run_schedule(key: str, planes: jnp.ndarray,
 
     planes: ``[n_inputs, W]`` uint32 — inputs concatenated in sorted-name
     order (matching ``CompiledSchedule.input_slots``).  Returns
-    ``[n_outputs, W]``.  W is padded to a BLOCK_WORDS multiple internally.
+    ``[n_outputs, W]``.  W is padded internally: to a ``BLOCK_WORDS``
+    multiple for the unrolled kernel, to whole loop-kernel word-blocks for
+    the loop kernel.
     ``mode`` selects the kernel: ``auto`` (by gate count), ``unrolled``
     (wave-scheduled straight line) or ``loop`` (fori_loop dispatch).
     Pallas runs in interpret mode exactly when ``planes`` live on the CPU.
@@ -469,17 +516,16 @@ def run_schedule(key: str, planes: jnp.ndarray,
             f"schedule {key!r} expects {len(compiled.input_slots)} stacked "
             f"input planes ({expected}, in sorted-name order), got "
             f"{planes.shape[0]}")
+    interpret = interpret_mode(planes)
+    if resolve_mode(compiled, mode) == "loop":
+        op, a, b, c, o = _gate_arrays(key)
+        return _run(op, a, b, c, o, planes, schedule_key=key,
+                    gen=_GENERATIONS.get(key, 0), interpret=interpret)
     W = planes.shape[1]
     pad = (-W) % BLOCK_WORDS
     if pad:
         planes = jnp.pad(planes, ((0, 0), (0, pad)))
-    interpret = interpret_mode(planes)
-    if resolve_mode(compiled, mode) == "unrolled":
-        out = _run_unrolled(compiled, key, planes, interpret)
-    else:
-        op, a, b, c, o = _gate_arrays(key)
-        out = _run(op, a, b, c, o, planes, schedule_key=key,
-                   gen=_GENERATIONS.get(key, 0), interpret=interpret)
+    out = _run_unrolled(compiled, key, planes, interpret)
     return out[:, :W]
 
 

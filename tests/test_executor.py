@@ -131,6 +131,60 @@ def test_fused_f32_mac_unrolled_bit_exact():
     assert np.array_equal(got_u, got_i)
 
 
+@pytest.mark.parametrize("basis", ["memristive", "dram"])
+@pytest.mark.parametrize("words", [1, 1023, 1024, 1025, 3136, 17413])
+def test_loop_kernel_word_blocks_bit_exact(words, basis):
+    """The loop kernel's ``(8, L)`` column tiles at any word count: padded
+    to whole word-blocks and trimmed, on one block or on several (17,413
+    words of the f32 MAC are 3 blocks at u = 6), bit-exact against the
+    interpreter."""
+    compiled = pim.compile(_MAC, dtype=pim.f32).compiled(basis=basis)
+    planes = _random_planes(len(compiled.input_slots), words, seed=words)
+    exp = np.asarray(ir.get_backend("interpreter").run(compiled, planes).planes)
+    got = ir.get_backend("pallas-loop").run(compiled, planes).planes
+    assert got.shape == exp.shape
+    assert np.array_equal(np.asarray(got), exp)
+
+
+def _vmem_planes(compiled):
+    """VMEM planes per word of ``pim_loop``: the state, the input and output
+    blocks double-buffered."""
+    return compiled.num_cols + 2 * (len(compiled.input_slots)
+                                    + len(compiled.output_slots))
+
+
+@pytest.mark.parametrize("words,units,blocks", [
+    (200704, 8, 25), (25088, 7, 4), (3136, 4, 1)],
+    ids=["mac_b8", "mac_b1", "conv"])
+def test_loop_block_units_at_the_cells_sizes(words, units, blocks):
+    """The benchmark cells' word counts at the memristive f32 MAC's 160
+    columns, 96 input and 32 output planes."""
+    compiled = pim.compile(_MAC, dtype=pim.f32).compiled(basis="memristive")
+    assert compiled.num_cols == 160
+    u = pim_bitserial.loop_block_units(words, _vmem_planes(compiled))
+    assert u == units
+    assert -(-words // (pim_bitserial.TILE_WORDS * u)) == blocks
+
+
+@pytest.mark.parametrize("planes", [1, 20, 320, 411, 416, 2000])
+def test_loop_block_units_fewest_blocks_then_least_padding(planes):
+    """Against a search over every width that fits ``LOOP_VMEM_BYTES``:
+    the fewest word-blocks, then the least padding; never past the VMEM
+    cap, and a prime unit count does not fall back to u = 1."""
+    tile_bytes = 4 * pim_bitserial.TILE_WORDS * planes
+    u_max = max(1, pim_bitserial.LOOP_VMEM_BYTES // tile_bytes)
+    for words in [1, 1023, 1024, 1025, 13 * 1024, 17 * 1024 - 1, 100_003,
+                  200_704]:
+        u = pim_bitserial.loop_block_units(words, planes)
+        units = -(-words // pim_bitserial.TILE_WORDS)
+        best = min(range(1, u_max + 1),
+                   key=lambda v: (-(-units // v), -(-units // v) * v))
+        assert u == best, (words, planes)
+        assert u <= u_max
+    if u_max > 1:
+        assert pim_bitserial.loop_block_units(13 * 1024, planes) > 1
+
+
 # --------------------------------------------------- scheduling invariants
 
 

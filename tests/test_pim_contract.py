@@ -59,7 +59,8 @@ def _assert_bits_equal(got, expect):
 
 @pytest.mark.parametrize("basis", ["memristive", "dram"])
 @pytest.mark.parametrize("dtype", sorted(_DTYPES))
-@pytest.mark.parametrize("mkn", [(1, 1, 32), (5, 3, 33), (40, 9, 64)],
+@pytest.mark.parametrize("mkn", [(1, 1, 32), (5, 3, 33), (40, 9, 64),
+                                 (360, 2, 1000)],
                          ids=lambda s: "x".join(map(str, s)))
 def test_contraction_matches_sequential_reference(mkn, dtype, basis):
     m, k, n = mkn
@@ -155,8 +156,9 @@ def test_contraction_call_is_three_programs(k):
 
 
 def test_contract_kernel_takes_step_planes_and_returns_the_result():
-    """The ``pallas_call`` takes the ``[K, 64, W]`` step planes and returns
-    only ``[32, W]``: no per-step accumulator array in HBM."""
+    """The ``pallas_call`` takes the ``[K, 64, W]`` step planes, as full-vreg
+    ``(8, L)`` tiles of whole word-blocks, and returns only the 32 result
+    planes: no per-step accumulator array in HBM."""
     fn = pim.compile(_MATMUL, dtype=pim.f32)
     compiled = fn.compiled("memristive")
     key = pim_bitserial.register_compiled(compiled)
@@ -164,10 +166,12 @@ def test_contract_kernel_takes_step_planes_and_returns_the_result():
     jaxpr = jax.make_jaxpr(lambda s: pim_bitserial._run_contract(
         *pim_bitserial._gate_arrays(key), s, num_cols=compiled.num_cols,
         slots=fn.program.slots(compiled), interpret=True))(steps)
+    assert [v.aval.shape for v in jaxpr.jaxpr.outvars] == [(32, 512)]
     inner = jaxpr.jaxpr.eqns[0].params["jaxpr"]
     calls = [e for e in inner.eqns if e.primitive.name == "pallas_call"]
     assert len(calls) == 1
     call = calls[0]
     assert call.params["name"] == pim_bitserial.CONTRACT_KERNEL
-    assert [v.aval.shape for v in call.invars][-1] == (5, 64, 512)
-    assert [v.aval.shape for v in call.outvars] == [(32, 512)]
+    # 512 words pad to one word-block of 1024: one (8, 128) tile per plane.
+    assert [v.aval.shape for v in call.invars][-1] == (5, 64, 8, 128)
+    assert [v.aval.shape for v in call.outvars] == [(32, 8, 128)]

@@ -24,6 +24,7 @@ from repro.kernels import pim_bitserial, pim_matmul
 W = 2 ** 15  # packed words per plane: 2^20 elements
 
 _MAC = lambda a, b, c: a * b + c  # noqa: E731
+_MATMUL = lambda a, b: a @ b  # noqa: E731
 
 
 @pytest.fixture(scope="module")
@@ -120,6 +121,42 @@ def test_contract_kernel_compiles(one_chip, basis):
         *gates, steps, num_cols=compiled.num_cols,
         slots=fn.program.slots(compiled), interpret=False)
     _assert_mosaic(lowered, kernel="pim_contract")
+
+
+@pytest.mark.parametrize("kernel", ["pim_loop", "pim_contract"])
+@pytest.mark.parametrize("basis", ["memristive", "dram"])
+def test_widest_tiles_compile(one_chip, kernel, basis):
+    """Each loop kernel at the widest ``(8, L)`` tiles its width rule picks
+    for the f32 MAC (160 memristive, 155 dram columns), over two
+    word-blocks: the planes the rule counts, and what Mosaic keeps besides,
+    fit the scoped VMEM."""
+    fn = pim.compile(_MATMUL if kernel == "pim_contract" else _MAC,
+                     dtype=pim.f32)
+    compiled = fn.compiled(basis)
+    n_out = len(compiled.output_slots)
+    if kernel == "pim_contract":
+        n_in = len(compiled.input_slots) - n_out
+        vmem_planes = compiled.num_cols + n_in + 3 * n_out
+    else:
+        n_in = len(compiled.input_slots)
+        vmem_planes = compiled.num_cols + 2 * (n_in + n_out)
+    u_max = pim_bitserial.LOOP_VMEM_BYTES // (
+        4 * pim_bitserial.TILE_WORDS * vmem_planes)
+    words = 2 * u_max * pim_bitserial.TILE_WORDS
+    assert pim_bitserial.loop_block_units(words, vmem_planes) == u_max > 1
+    gates = [_sds((compiled.num_gates,), jnp.int32, one_chip)] * 5
+    if kernel == "pim_contract":
+        steps = _sds((2, n_in, words), jnp.uint32, one_chip)
+        lowered = pim_bitserial._run_contract.lower(
+            *gates, steps, num_cols=compiled.num_cols,
+            slots=fn.program.slots(compiled), interpret=False)
+    else:
+        key = pim_bitserial.register_compiled(compiled)
+        planes = _sds((n_in, words), jnp.uint32, one_chip)
+        lowered = pim_bitserial._run.lower(
+            *gates, planes, schedule_key=key,
+            gen=pim_bitserial._GENERATIONS.get(key, 0), interpret=False)
+    _assert_mosaic(lowered, kernel=kernel)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
