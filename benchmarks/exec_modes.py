@@ -1,11 +1,14 @@
-"""Executor-mode shootout: wave-scheduled straight-line vs fori_loop.
+"""Executor-kernel shootout: wave-scheduled straight-line vs fori_loop.
 
-Races the ``pallas-unrolled`` kernel against the ``pallas-loop`` kernel (and
-the ``interpreter`` scan oracle) on fused MAC programs, reporting per mode
-the schedule shape the compiler produced — gates, peak columns after the
-``reorder`` pass, dependency waves (``parallel_cycles``) — next to measured
-wall time per dispatch.  ``benchmarks/run.py --json BENCH_exec.json`` emits
-the rows as JSON, and ``benchmarks/smoke.py`` checks that they are there.
+Races the unrolled kernel (``pim_bitserial.run_unrolled``) against the loop
+kernel (``run_loop``) and the ``interpreter`` scan oracle on fused MAC
+programs, reporting per kernel the schedule shape the compiler produced —
+gates, peak columns after the ``reorder`` pass, dependency waves
+(``parallel_cycles``) — next to measured wall time per dispatch, and the
+kernel the ``pallas`` backend picks (``auto_kernel``).  Rows are named
+``exec/<case>/<basis>/{interpreter,loop,unrolled}``.
+``benchmarks/run.py --json BENCH_exec.json`` emits the rows as JSON, and
+``benchmarks/smoke.py`` checks that they are there.
 
 The first unrolled dispatch pays the straight-line XLA compile (tens of
 seconds for the 13k-gate f32 MAC — the schedule splits into
@@ -29,9 +32,7 @@ from .common import run_cli, time_fn
 
 N_ELEMS = 4096
 
-_MODES = ("interpreter", "pallas-loop", "pallas-unrolled")
-
-# dtype rows: f32 is the CI-gated case; int8 shows the auto threshold
+# dtype rows: f32 is the CI-gated case; int8 shows the gate-count threshold
 # picking `unrolled` on its own.
 _CASES = {"f32_mac": pim.f32, "int8_mac": pim.int8}
 
@@ -52,30 +53,32 @@ def run(bases: tuple[str, ...] = ("memristive",),
         passes: tuple[str, ...] | None = None) -> list[dict]:
     from repro.kernels import pim_bitserial
 
+    interpreter = ir.get_backend("interpreter")
+    runners = {"interpreter": lambda c, p: interpreter.run(c, p).planes,
+               **pim_bitserial.KERNELS}
     passes = ir.DEFAULT_PASSES if passes is None else passes
     rng = np.random.default_rng(0)
     rows = []
     for name, dtype in _CASES.items():
         mac = pim.compile(lambda a, b, c: a * b + c, dtype=dtype)
         # Time the executor dispatch alone, on pre-packed planes — plane
-        # pack/unpack is shared by every mode and would otherwise drown the
+        # pack/unpack is shared by every kernel and would otherwise drown the
         # kernel difference.
         planes = _planes(mac, dtype, rng)
         for basis in bases:
             compiled = mac.compiled(basis=basis, passes=passes)
-            for mode in _MODES:
-                backend = ir.get_backend(mode)
+            for kernel, run_kernel in runners.items():
                 us = time_fn(
-                    lambda backend=backend, c=compiled:
-                        backend.run(c, planes).planes,
+                    lambda run_kernel=run_kernel, c=compiled:
+                        run_kernel(c, planes),
                     warmup=1, iters=3)
                 rows.append({
-                    "name": f"exec/{name}/{basis}/{mode}",
+                    "name": f"exec/{name}/{basis}/{kernel}",
                     "us_per_call": f"{us:.0f}",
                     "gates": compiled.num_gates,
                     "num_cols": compiled.num_cols,
                     "waves": compiled.num_waves,
-                    "auto_mode": pim_bitserial.resolve_mode(compiled),
+                    "auto_kernel": pim_bitserial.auto_kernel(compiled),
                 })
     return rows
 

@@ -7,7 +7,7 @@
   fig6_cnn_infer  — §5 CNN inference
   fig7_cnn_train  — §5 CNN training
 
-Prints ``name,us_per_call,derived`` CSV.  The executor-mode shootout
+Prints ``name,us_per_call,derived`` CSV.  The executor-kernel shootout
 (``exec_modes``, unrolled vs fori_loop) is not part of the default sweep —
 its straight-line compile is expensive; run it via ``--json PATH`` (which
 runs only that benchmark and writes its rows as JSON, the
@@ -26,7 +26,7 @@ from repro import compile_cache
 
 
 def write_exec_json(path: str) -> list[dict]:
-    """Run the executor-mode benchmark and write its rows to ``path``."""
+    """Run the executor-kernel benchmark and write its rows to ``path``."""
     from . import exec_modes
     from .common import emit
 
@@ -43,8 +43,8 @@ def main(argv=None) -> None:
     parser = argparse.ArgumentParser(description="ConvPIM benchmark harness")
     parser.add_argument(
         "--json", metavar="BENCH_exec.json", default=None,
-        help="run only the executor-mode benchmark and write its rows "
-             "(gates, num_cols, waves, us per executor mode) as JSON")
+        help="run only the executor-kernel benchmark and write its rows "
+             "(gates, num_cols, waves, us per executor kernel) as JSON")
     args = parser.parse_args(argv)
 
     compile_cache.enable()

@@ -8,7 +8,7 @@ particular performance number.  The compile-cache hit/miss counters are
 printed at the end so cache regressions (e.g. a wrapper recompiling what
 ``compile_op`` already built) are visible in CI logs.
 
-The executor-mode benchmark (``exec_modes``, emitted to
+The executor-kernel benchmark (``exec_modes``, emitted to
 ``BENCH_exec.json``) must produce its f32 MAC rows with their exact schedule
 counts.  Its CPU timings are not checked: on the CPU both Pallas kernels run
 in interpret mode, which says nothing about their speed on a TPU.
@@ -53,12 +53,12 @@ def check(name: str, rows: list[dict]) -> None:
 
 
 def check_exec(rows: list[dict]) -> None:
-    """Every executor mode ran the f32 MAC and reported its counts."""
+    """Both executor kernels ran the f32 MAC and reported its counts."""
     names = {r["name"]: r for r in rows}
-    for mode in ("pallas-loop", "pallas-unrolled"):
-        row = names.get(f"exec/f32_mac/memristive/{mode}")
+    for kernel in ("loop", "unrolled"):
+        row = names.get(f"exec/f32_mac/memristive/{kernel}")
         if row is None or not all(row.get(k) for k in ("gates", "num_cols", "waves")):
-            raise SystemExit(f"smoke: exec_modes is missing the f32 MAC {mode} row")
+            raise SystemExit(f"smoke: exec_modes is missing the f32 MAC {kernel} row")
     print(f"smoke: exec ok ({len(rows)} rows)", file=sys.stderr)
 
 

@@ -57,13 +57,13 @@ MATMUL_TOL = 1e-5
 
 @dataclasses.dataclass(frozen=True)
 class Elementwise:
-    """One ``pim.compile`` program run on one basis and executor mode."""
+    """One ``pim.compile`` program run on one basis and executor kernel."""
 
     name: str
     fn: Callable
     dtype: str  # "f32" | "int8"
     basis: str
-    mode: str | None  # None = the backend's gate-count choice
+    kernel: str | None  # a ``pim_bitserial.KERNELS`` name; None = the backend's choice
     elements: int
 
 
@@ -155,6 +155,7 @@ def run_elementwise(phase: Elementwise, seed: int = SEED) -> dict:
     import jax
 
     import repro.pim as pim
+    from repro.core import bitplanes
     from repro.kernels import pim_bitserial
 
     rng = np.random.default_rng(seed)
@@ -170,7 +171,11 @@ def run_elementwise(phase: Elementwise, seed: int = SEED) -> dict:
     trace_s = time.perf_counter() - t0
 
     def call():
-        return fn(*dev_args, basis=phase.basis, mode=phase.mode)
+        if phase.kernel is None:
+            return fn(*dev_args, basis=phase.basis)
+        planes = bitplanes.pack(fn.in_types, *dev_args)
+        out = pim_bitserial.KERNELS[phase.kernel](compiled, planes)
+        return bitplanes.unpack(fn.out_types, out, phase.elements)[0]
 
     t0 = time.perf_counter()
     out = jax.block_until_ready(call())
@@ -178,8 +183,8 @@ def run_elementwise(phase: Elementwise, seed: int = SEED) -> dict:
     steady_s = _steady_seconds(call)
 
     got = np.asarray(out)
-    kernel = pim_bitserial.resolve_mode(compiled, phase.mode or "auto")
-    segments = (len(pim_bitserial._segments(compiled))
+    kernel = phase.kernel or pim_bitserial.auto_kernel(compiled)
+    segments = (len(pim_bitserial.executor_plan(compiled).segments)
                 if kernel == "unrolled" else 1)
     result = {
         "kernel": kernel,

@@ -39,11 +39,11 @@ list scheduler that shortens live ranges before the linear-scan allocator,
 cutting ``num_cols``/``peak_rows`` (never increasing them — DESIGN.md §5).
 
 Executor backends share one interface (``Backend.run``) and live in a
-registry: ``interpreter`` (pure-jnp scan), ``pallas`` / ``pallas-unrolled``
-/ ``pallas-loop`` (the TPU kernels in ``repro.kernels.pim_bitserial``,
-registered lazily) and ``cost`` (analytical gate/cycle model — no data
-movement at all).  Compiled schedules are cached
-by ``(program, basis, pass_list)`` so every consumer (``kernels.ops``,
+registry: ``interpreter`` (pure-jnp scan), ``pallas`` (the TPU kernels in
+``repro.kernels.pim_bitserial``, registered lazily) and ``cost``
+(analytical gate/cycle model — no data movement at all).  Compiled schedules
+are cached by ``(program, basis, pass_list)``, one identity-hashed
+``CompiledSchedule`` each, so every consumer (``kernels.ops``,
 ``core.simulate``, ``core.analyzer``, benchmarks) pulls from one path.
 
 Registering a new op = one entry in ``aritpim._OP_TABLE``; a new backend =
@@ -667,9 +667,10 @@ def run_passes(ir: ScheduleIR, passes: tuple[str, ...] = DEFAULT_PASSES,
 # ---------------------------------------------------------------------------
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)
 class CompiledSchedule:
     """Column-machine program with static I/O slot maps — what backends run.
+    Hashed and compared by identity.
 
     ``num_cols`` is the linear-scan high-water mark, i.e. the peak number of
     simultaneously live crossbar columns/rows (operands + intermediates); the
@@ -1073,9 +1074,8 @@ def compile_program(
         recorded.meta["prepass_gates"] = recorded.gate_count(basis)
         recorded.meta["prepass_len"] = recorded.num_gates
     baseline_cols = lower(recorded, basis=basis).num_cols
-    # The schedule key must be unique per *structure* (it names jit-static
-    # slot maps in the Pallas registry); the human-readable program name
-    # alone could collide across different traced lambdas.
+    # The key names the schedule in reports; the digest keeps it unique per
+    # *structure*, as traced lambdas' names alone could collide.
     digest = hashlib.sha1(program.key.encode()).hexdigest()[:8]
     key = (f"{program.name}@{digest}/{basis.name}/"
            f"{'+'.join(passes) if passes else 'raw'}")
@@ -1243,10 +1243,9 @@ def register_backend(backend: Backend) -> Backend:
 
 
 def get_backend(name: str) -> Backend:
-    if name not in _BACKENDS and name.startswith("pallas"):
-        # The Pallas executors (pallas / pallas-unrolled / pallas-loop)
-        # register themselves on import; kept lazy so core never
-        # hard-depends on jax.experimental.pallas.
+    if name not in _BACKENDS and name == "pallas":
+        # The Pallas executor registers itself on import; kept lazy so core
+        # never hard-depends on jax.experimental.pallas.
         import repro.kernels.pim_bitserial  # noqa: F401
     return _BACKENDS[name]
 

@@ -8,9 +8,8 @@ cache (single-op traces canonicalize to the same cache entries as
 when the arrays are on the CPU, the compiled kernel anywhere else).  Adding
 a wrapper is a registration, not a new code path, and every wrapper takes
 ``basis="memristive"|"dram"`` to execute the NOR or the MAJ3/NOT lowering of
-the same netlist, plus
-``mode="auto"|"unrolled"|"loop"`` to pick the executor kernel (wave-scheduled
-straight-line vs fori_loop; auto selects by gate count — DESIGN.md §5).
+the same netlist; the backend picks the executor kernel by gate count
+(DESIGN.md §5).
 ``pim_matmul`` is the MatPIM-schedule blocked matmul.
 """
 
@@ -38,55 +37,51 @@ def _fn(arith: str, dtype_name: str, nbits: int) -> pim.CompiledPimFunction:
     return pim.compile(_ARITH_FNS[arith], dtype=dtype, backend="pallas")
 
 
-def pim_float_add(x, y, basis: str = "memristive", mode: str | None = None):
-    return _fn("add", "f32", 32)(x, y, basis=basis, mode=mode)
+def pim_float_add(x, y, basis: str = "memristive"):
+    return _fn("add", "f32", 32)(x, y, basis=basis)
 
 
-def pim_float_sub(x, y, basis: str = "memristive", mode: str | None = None):
-    return _fn("sub", "f32", 32)(x, y, basis=basis, mode=mode)
+def pim_float_sub(x, y, basis: str = "memristive"):
+    return _fn("sub", "f32", 32)(x, y, basis=basis)
 
 
-def pim_float_mul(x, y, basis: str = "memristive", mode: str | None = None):
-    return _fn("mul", "f32", 32)(x, y, basis=basis, mode=mode)
+def pim_float_mul(x, y, basis: str = "memristive"):
+    return _fn("mul", "f32", 32)(x, y, basis=basis)
 
 
-def pim_float_div(x, y, basis: str = "memristive", mode: str | None = None):
-    return _fn("div", "f32", 32)(x, y, basis=basis, mode=mode)
+def pim_float_div(x, y, basis: str = "memristive"):
+    return _fn("div", "f32", 32)(x, y, basis=basis)
 
 
-def pim_bf16_add(x, y, basis: str = "memristive", mode: str | None = None):
-    return _fn("add", "bf16", 16)(x, y, basis=basis, mode=mode)
+def pim_bf16_add(x, y, basis: str = "memristive"):
+    return _fn("add", "bf16", 16)(x, y, basis=basis)
 
 
-def pim_bf16_sub(x, y, basis: str = "memristive", mode: str | None = None):
-    return _fn("sub", "bf16", 16)(x, y, basis=basis, mode=mode)
+def pim_bf16_sub(x, y, basis: str = "memristive"):
+    return _fn("sub", "bf16", 16)(x, y, basis=basis)
 
 
-def pim_bf16_mul(x, y, basis: str = "memristive", mode: str | None = None):
-    return _fn("mul", "bf16", 16)(x, y, basis=basis, mode=mode)
+def pim_bf16_mul(x, y, basis: str = "memristive"):
+    return _fn("mul", "bf16", 16)(x, y, basis=basis)
 
 
-def pim_fixed_add(x, y, nbits: int = 32,
-                  basis: str = "memristive", mode: str | None = None):
-    return _fn("add", "fixed", nbits)(x, y, basis=basis, mode=mode)
+def pim_fixed_add(x, y, nbits: int = 32, basis: str = "memristive"):
+    return _fn("add", "fixed", nbits)(x, y, basis=basis)
 
 
-def pim_fixed_sub(x, y, nbits: int = 32,
-                  basis: str = "memristive", mode: str | None = None):
-    return _fn("sub", "fixed", nbits)(x, y, basis=basis, mode=mode)
+def pim_fixed_sub(x, y, nbits: int = 32, basis: str = "memristive"):
+    return _fn("sub", "fixed", nbits)(x, y, basis=basis)
 
 
-def pim_fixed_mul(x, y, nbits: int = 32,
-                  basis: str = "memristive", mode: str | None = None):
+def pim_fixed_mul(x, y, nbits: int = 32, basis: str = "memristive"):
     """Signed N×N multiply; returns the low N bits (wrapping, like int mul)."""
-    return _fn("mul", "fixed", nbits)(x, y, basis=basis, mode=mode)
+    return _fn("mul", "fixed", nbits)(x, y, basis=basis)
 
 
-def pim_fixed_div(x, y, nbits: int = 32,
-                  basis: str = "memristive", mode: str | None = None):
+def pim_fixed_div(x, y, nbits: int = 32, basis: str = "memristive"):
     """Signed division (C truncation semantics); x//0 is the netlist's
     documented all-ones convention."""
-    return _fn("div", "fixed", nbits)(x, y, basis=basis, mode=mode)
+    return _fn("div", "fixed", nbits)(x, y, basis=basis)
 
 
 def pim_matmul_op(a, b, *, bm=128, bk=128, bn=128):

@@ -10,15 +10,15 @@ the ``repro.pim`` frontend.  HBM traffic is exactly the program's boundary
 planes (``CostReport.hbm_planes``) — independent of schedule length, the
 in-memory property the paper models.
 
-Two executor modes share the registry (DESIGN.md §5):
+Two kernels run an element-wise schedule (DESIGN.md §5):
 
-* ``loop`` — the original ``fori_loop`` kernel: one gate per iteration,
+* ``pim_loop`` — the ``fori_loop`` kernel: one gate per iteration,
   a dynamic read and write of whole column tiles plus a five-deep
   ``jnp.where`` opcode select, with the five gate arrays held in SMEM (5
   int32 words per gate; v5e's 1 MiB of SMEM holds schedules of up to about
   50k gates, and the TPU compiler refuses longer ones).  O(1) compile in
   schedule length, but each gate pays dynamic-indexing and select overhead.
-* ``unrolled`` — a **wave-scheduled straight-line** kernel generated from
+* ``pim_segment_<k>`` — a **wave-scheduled straight-line** kernel generated from
   the fact that ``(op, a, b, c, o)`` are static per ``CompiledSchedule``:
   the body is Python-unrolled bitwise ops on fixed ``state[col]`` indices —
   no dynamic indexing, no opcode-select chain, no scalar gate arrays on the
@@ -40,14 +40,11 @@ Pallas runs in interpret mode exactly when the input planes live on the CPU
 (``repro.kernels.interpret_mode``), so tests on the CPU trace the same kernel
 bodies that the TPU compiles.
 
-The ``pallas`` backend picks the mode automatically by gate count
-(``UNROLL_AUTO_MAX_GATES``): short schedules unroll, very long ones fall
-back to the loop kernel.  ``pallas-unrolled`` / ``pallas-loop`` force one
-mode (``benchmarks/exec_modes.py`` runs both on the f32 fused MAC).
-Per-schedule artifacts — the gate arrays and their device
-upload for the loop kernel, the wave-chunked segments for the unrolled
-kernel — are cached by schedule key, so repeat dispatches stop rebuilding
-and re-transferring them.
+The one ``pallas`` backend runs ``run_unrolled`` up to ``UNROLL_AUTO_MAX_GATES``
+gates and ``run_loop`` past it (``auto_kernel``).  Every kernel takes as its
+jit-static argument the schedule's ``ExecutorPlan`` (device gate arrays, slot
+maps, lazily built segments), built once per ``CompiledSchedule`` object and
+hashed by identity: O(1) per call, and no trace is shared between schedules.
 
 Tiling: the grid runs over blocks of the packed-words axis; each program
 holds the *entire* (column-allocated) crossbar state for its word-block.
@@ -66,6 +63,7 @@ is the unrolled segment kernel's block alone: ``[num_cols, 256]`` rows.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -80,7 +78,6 @@ from repro.core.machine import (
     OP_MAJ3,
     OP_NOR,
     OP_NOT,
-    Schedule,
     operand_slots,
 )
 from repro.kernels import interpret_mode
@@ -96,15 +93,13 @@ TILE_WORDS = SUBLANES * LANES
 LOOP_VMEM_BYTES = 14 * 2**20
 UMAX32 = 0xFFFFFFFF  # python int: folded into the kernel, not a captured array
 
-# Mode-auto threshold: schedules at or below this many gates unroll; longer
-# ones keep the fori_loop kernel (straight-line XLA compile time grows
+# Kernel choice: schedules at or below this many gates unroll; longer ones
+# run the fori_loop kernel (straight-line XLA compile time grows
 # superlinearly, so unrolling a 20k-gate divider buys compile pain for a
-# win the loop kernel amortizes anyway).  Force a mode with the
-# ``pallas-unrolled`` / ``pallas-loop`` backends.
+# win the loop kernel amortizes anyway).
 UNROLL_AUTO_MAX_GATES = 1024
 # Straight-line gates per generated segment kernel; boundaries snap to wave
-# chunk edges.  ~4 s of XLA-CPU compile per segment, amortized by the
-# per-key segment cache.
+# chunk edges.  ~4 s of XLA-CPU compile per segment, paid once per schedule.
 UNROLL_SEGMENT_GATES = 1024
 # Kernel names in the compiled HLO and a profiler trace: the loop kernel,
 # and ``<SEGMENT_KERNEL>_<k>`` for the k-th unrolled segment.
@@ -115,7 +110,7 @@ CONTRACT_KERNEL = "pim_contract"
 
 
 # ---------------------------------------------------------------------------
-# fori_loop kernel (the `loop` mode)
+# fori_loop kernel (`pim_loop`)
 # ---------------------------------------------------------------------------
 
 
@@ -183,32 +178,28 @@ def _kernel(op_ref, a_ref, b_ref, c_ref, o_ref, in_ref, out_ref, state, *,
         out_ref[i] = state[col]
 
 
-@functools.partial(jax.jit, static_argnames=("schedule_key", "gen", "interpret"))
-def _run(op, a, b, c, o, planes, *, schedule_key, gen, interpret):
+@functools.partial(jax.jit, static_argnames=("plan", "interpret"))
+def _run(op, a, b, c, o, planes, *, plan, interpret):
     """``planes`` ``[n_in, W]`` → ``[n_out, W]``, run over word-blocks of
     ``loop_block_units`` full-vreg tiles per column."""
-    # `gen` bumps when a different schedule is registered under this key, so
-    # traces that baked the old static slot maps are never reused.
-    compiled = _SCHEDULES[schedule_key]
-    input_slots = compiled.input_slots
-    output_slots = compiled.output_slots
     n_in, W = planes.shape
-    n_out = len(output_slots)
-    units = loop_block_units(W, compiled.num_cols + 2 * (n_in + n_out))
+    n_out = len(plan.output_slots)
+    units = loop_block_units(W, plan.num_cols + 2 * (n_in + n_out))
     tiles = _to_tiles(planes, units)
     rows, L = tiles.shape[1:]
     # The gate arrays are read one scalar per iteration at a dynamic index,
     # which Mosaic allows from SMEM but not from VMEM vectors.
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out = pl.pallas_call(
-        functools.partial(_kernel, input_slots=tuple(input_slots), output_slots=tuple(output_slots)),
+        functools.partial(_kernel, input_slots=plan.input_slots,
+                          output_slots=plan.output_slots),
         grid=(rows // SUBLANES,),
         in_specs=[smem] * 5 + [
             pl.BlockSpec((n_in, SUBLANES, L), lambda i: (0, i, 0)),
         ],
         out_specs=pl.BlockSpec((n_out, SUBLANES, L), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_out, rows, L), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((compiled.num_cols, SUBLANES, L), jnp.uint32)],
+        scratch_shapes=[pltpu.VMEM((plan.num_cols, SUBLANES, L), jnp.uint32)],
         interpret=interpret,
         name=LOOP_KERNEL,
     )(op, a, b, c, o, tiles)
@@ -264,8 +255,8 @@ def _contract_kernel(op_ref, a_ref, b_ref, c_ref, o_ref, steps_hbm, out_ref,
         out_ref[...] = carry[...]
 
 
-@functools.partial(jax.jit, static_argnames=("num_cols", "slots", "interpret"))
-def _run_contract(op, a, b, c, o, steps, *, num_cols, slots, interpret):
+@functools.partial(jax.jit, static_argnames=("plan", "slots", "interpret"))
+def _run_contract(op, a, b, c, o, steps, *, plan, slots, interpret):
     """``steps`` ``[K, operand planes, W]`` → the carry's ``[width, W]``
     planes, run over word-blocks of ``loop_block_units`` full-vreg tiles per
     column.  ``slots`` is ``Contraction.slots`` of the schedule."""
@@ -274,7 +265,7 @@ def _run_contract(op, a, b, c, o, steps, *, num_cols, slots, interpret):
     n_out = len(output_slots)
     # VMEM: the state, the carry, one step's operands, the output block
     # double-buffered.
-    units = loop_block_units(W, num_cols + n_in + 3 * n_out)
+    units = loop_block_units(W, plan.num_cols + n_in + 3 * n_out)
     tiles = _to_tiles(steps, units)
     rows, L = tiles.shape[2:]
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
@@ -285,7 +276,7 @@ def _run_contract(op, a, b, c, o, steps, *, num_cols, slots, interpret):
         in_specs=[smem] * 5 + [pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((n_out, SUBLANES, L), lambda i, k: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_out, rows, L), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((num_cols, SUBLANES, L), jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((plan.num_cols, SUBLANES, L), jnp.uint32),
                         pltpu.VMEM((n_out, SUBLANES, L), jnp.uint32),
                         pltpu.VMEM((n_in, SUBLANES, L), jnp.uint32),
                         pltpu.SemaphoreType.DMA(())],
@@ -298,7 +289,7 @@ def _run_contract(op, a, b, c, o, steps, *, num_cols, slots, interpret):
 
 
 # ---------------------------------------------------------------------------
-# Wave-scheduled straight-line kernel (the `unrolled` mode)
+# Wave-scheduled straight-line kernel (`pim_segment_<k>`)
 # ---------------------------------------------------------------------------
 
 
@@ -385,10 +376,8 @@ def _unrolled_segment_kernel(state_ref, out_ref, *, chunks, num_cols):
 _SEGMENT_COMPILER_OPTIONS = {"xla_cpu_use_fusion_emitters": False}
 
 
-def _unrolled_segment(state, *, schedule_key, gen, seg, interpret):
-    # `gen` bumps when a different schedule is registered under this key, so
-    # traces that baked the old gate list are never reused.
-    chunks = _segment_cache(schedule_key)[seg]
+def _unrolled_segment(state, *, plan, seg, interpret):
+    chunks = plan.segments[seg]
     num_cols, W = state.shape
     return pl.pallas_call(
         functools.partial(_unrolled_segment_kernel, chunks=chunks,
@@ -403,7 +392,7 @@ def _unrolled_segment(state, *, schedule_key, gen, seg, interpret):
     )(state)
 
 
-_SEGMENT_JIT = dict(static_argnames=("schedule_key", "gen", "seg", "interpret"),
+_SEGMENT_JIT = dict(static_argnames=("plan", "seg", "interpret"),
                     donate_argnums=0)
 _run_unrolled_segment = jax.jit(_unrolled_segment, **_SEGMENT_JIT,
                                 compiler_options=_SEGMENT_COMPILER_OPTIONS)
@@ -412,164 +401,108 @@ _run_unrolled_segment = jax.jit(_unrolled_segment, **_SEGMENT_JIT,
 _run_unrolled_segment_nested = jax.jit(_unrolled_segment, **_SEGMENT_JIT)
 
 
-def _run_unrolled(compiled: ir.CompiledSchedule, key: str, planes, interpret):
-    gen = _GENERATIONS.get(key, 0)
-    state = jnp.zeros((compiled.num_cols, planes.shape[1]), jnp.uint32)
-    state = state.at[jnp.asarray(compiled.input_slots)].set(
-        jnp.asarray(planes, jnp.uint32))
-    run = (_run_unrolled_segment_nested if isinstance(planes, jax.core.Tracer)
-           else _run_unrolled_segment)
-    for seg in range(len(_segment_cache(key))):
-        state = run(state, schedule_key=key, gen=gen, seg=seg,
-                    interpret=interpret)
-    return state[jnp.asarray(compiled.output_slots)]
-
-
 # ---------------------------------------------------------------------------
-# Per-schedule caches and dispatch
+# Executor plans and dispatch
 # ---------------------------------------------------------------------------
 
-# Registry of compiled schedules (keyed so jit can treat them as static).
-_SCHEDULES: dict[str, ir.CompiledSchedule] = {}
-# Device-resident gate arrays for the loop kernel, built/uploaded once per
-# key instead of per call.
-_GATE_ARRAYS: dict[str, tuple] = {}
-# Wave-chunked straight-line segments for the unrolled kernel.
-_SEGMENTS: dict[str, list] = {}
-# Bumped when a key is rebound to different schedule content; part of the
-# kernels' static jit keys, so stale traces are never replayed.
-_GENERATIONS: dict[str, int] = {}
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class ExecutorPlan:
+    """What the kernels take of one ``CompiledSchedule``: their jit-static
+    argument, identity-hashed, so O(1) per call and no shared trace."""
+
+    schedule: ir.CompiledSchedule
+    gates: tuple  # (op, a, b, c, o) int32 device arrays, for the loop kernels
+    num_cols: int
+    input_slots: tuple[int, ...]
+    output_slots: tuple[int, ...]
+
+    @functools.cached_property
+    def segments(self) -> list:
+        """The unrolled kernel's wave-chunked segments, built on first use."""
+        return _segments(self.schedule)
 
 
-def _invalidate(key: str) -> None:
-    _GATE_ARRAYS.pop(key, None)
-    _SEGMENTS.pop(key, None)
-    _GENERATIONS[key] = _GENERATIONS.get(key, 0) + 1
+@functools.cache
+def executor_plan(compiled: ir.CompiledSchedule) -> ExecutorPlan:
+    """The plan of ``compiled``, built once per schedule object."""
+    # Concrete device arrays even when first built under a caller's jit,
+    # so no tracer is cached.
+    with jax.ensure_compile_time_eval():
+        gates = tuple(jax.device_put(a) for a in compiled.as_arrays())
+    return ExecutorPlan(compiled, gates, compiled.num_cols,
+                        tuple(compiled.input_slots),
+                        tuple(compiled.output_slots))
 
 
-def _gate_arrays(key: str) -> tuple:
-    arrays = _GATE_ARRAYS.get(key)
-    if arrays is None:
-        # Concrete device arrays even when first built under a caller's jit,
-        # so no tracer is cached.
-        with jax.ensure_compile_time_eval():
-            arrays = _GATE_ARRAYS[key] = tuple(
-                jax.device_put(a) for a in _SCHEDULES[key].as_arrays())
-    return arrays
-
-
-def _segment_cache(key: str) -> list:
-    segments = _SEGMENTS.get(key)
-    if segments is None:
-        segments = _SEGMENTS[key] = _segments(_SCHEDULES[key])
-    return segments
-
-
-def register_compiled(compiled: ir.CompiledSchedule, key: str | None = None) -> str:
-    key = key or compiled.key
-    if _SCHEDULES.get(key) is not compiled:
-        _invalidate(key)
-    _SCHEDULES[key] = compiled
-    return key
-
-
-def register_schedule(key: str, schedule: Schedule | ir.CompiledSchedule) -> None:
-    """Register a schedule under ``key``.  Accepts a ``CompiledSchedule`` or a
-    legacy (column-allocated) ``machine.Schedule``, which is wrapped as-is."""
-    if isinstance(schedule, ir.CompiledSchedule):
-        register_compiled(schedule, key)
-        return
-    _invalidate(key)
-    _SCHEDULES[key] = ir.CompiledSchedule.from_legacy(schedule, key=key)
-
-
-def resolve_mode(compiled: ir.CompiledSchedule, mode: str = "auto") -> str:
-    """``auto`` picks by gate count; ``unrolled``/``loop`` force a kernel."""
-    if mode == "auto":
-        return ("unrolled" if compiled.num_gates <= UNROLL_AUTO_MAX_GATES
-                else "loop")
-    if mode not in ("unrolled", "loop"):
-        raise ValueError(f"unknown executor mode {mode!r} "
-                         "(expected 'auto', 'unrolled' or 'loop')")
-    return mode
-
-
-def run_schedule(key: str, planes: jnp.ndarray,
-                 mode: str = "auto") -> jnp.ndarray:
-    """Execute registered schedule ``key`` over stacked input planes.
-
-    planes: ``[n_inputs, W]`` uint32 — inputs concatenated in sorted-name
-    order (matching ``CompiledSchedule.input_slots``).  Returns
-    ``[n_outputs, W]``.  W is padded internally: to a ``BLOCK_WORDS``
-    multiple for the unrolled kernel, to whole loop-kernel word-blocks for
-    the loop kernel.
-    ``mode`` selects the kernel: ``auto`` (by gate count), ``unrolled``
-    (wave-scheduled straight line) or ``loop`` (fori_loop dispatch).
-    Pallas runs in interpret mode exactly when ``planes`` live on the CPU.
-    """
-    compiled = _SCHEDULES[key]
-    planes = jnp.asarray(planes)
-    if planes.shape[0] != len(compiled.input_slots):
-        expected = {name: len(cols)
-                    for name, cols in sorted(compiled.input_cols.items())}
+def _plan_for(compiled: ir.CompiledSchedule, planes) -> ExecutorPlan:
+    plan = executor_plan(compiled)
+    if planes.shape[0] != len(plan.input_slots):
+        expected = {k: len(v) for k, v in sorted(compiled.input_cols.items())}
         raise ValueError(
-            f"schedule {key!r} expects {len(compiled.input_slots)} stacked "
-            f"input planes ({expected}, in sorted-name order), got "
+            f"schedule {compiled.key!r} expects {len(plan.input_slots)} stacked"
+            f" input planes ({expected}, in sorted-name order), got "
             f"{planes.shape[0]}")
+    return plan
+
+
+def run_loop(compiled: ir.CompiledSchedule, planes) -> jnp.ndarray:
+    """``compiled`` on ``pim_loop``: ``planes`` ``[n_inputs, W]`` uint32, the
+    inputs in sorted-name order (``input_slots``) → ``[n_outputs, W]``."""
+    planes = jnp.asarray(planes)
+    plan = _plan_for(compiled, planes)
+    return _run(*plan.gates, planes, plan=plan,
+                interpret=interpret_mode(planes))
+
+
+def run_unrolled(compiled: ir.CompiledSchedule, planes) -> jnp.ndarray:
+    """As ``run_loop``, on the ``pim_segment_<k>`` kernels over the
+    ``[num_cols, W]`` state, W padded to a ``BLOCK_WORDS`` multiple."""
+    planes = jnp.asarray(planes)
+    plan = _plan_for(compiled, planes)
     interpret = interpret_mode(planes)
-    if resolve_mode(compiled, mode) == "loop":
-        op, a, b, c, o = _gate_arrays(key)
-        return _run(op, a, b, c, o, planes, schedule_key=key,
-                    gen=_GENERATIONS.get(key, 0), interpret=interpret)
     W = planes.shape[1]
     pad = (-W) % BLOCK_WORDS
     if pad:
         planes = jnp.pad(planes, ((0, 0), (0, pad)))
-    out = _run_unrolled(compiled, key, planes, interpret)
-    return out[:, :W]
+    state = jnp.zeros((plan.num_cols, W + pad), jnp.uint32)
+    state = state.at[jnp.asarray(plan.input_slots)].set(
+        jnp.asarray(planes, jnp.uint32))
+    run = (_run_unrolled_segment_nested if isinstance(planes, jax.core.Tracer)
+           else _run_unrolled_segment)
+    for seg in range(len(plan.segments)):
+        state = run(state, plan=plan, seg=seg, interpret=interpret)
+    return state[jnp.asarray(plan.output_slots)][:, :W]
+
+
+KERNELS = {"loop": run_loop, "unrolled": run_unrolled}
+
+
+def auto_kernel(compiled: ir.CompiledSchedule) -> str:
+    """The ``KERNELS`` entry the ``pallas`` backend runs ``compiled`` on."""
+    return ("unrolled" if compiled.num_gates <= UNROLL_AUTO_MAX_GATES
+            else "loop")
 
 
 class PallasBackend(ir.Backend):
-    """TPU executor: one VMEM-resident crossbar per word-block, kernel mode
+    """TPU executor: one VMEM-resident crossbar per word-block, the kernel
     chosen by gate count (on the CPU the same kernels run in Pallas
-    interpret mode).  ``opts['mode']`` overrides the selection per call."""
+    interpret mode)."""
 
     name = "pallas"
-    mode = "auto"
 
-    def run(self, compiled, planes=None, mode: str | None = None, **opts):
+    def run(self, compiled, planes=None, **opts):
         if planes is None:
             raise ValueError(f"{self.name} backend needs input planes")
-        key = register_compiled(compiled)
-        out = run_schedule(key, planes, mode=mode or self.mode)
+        out = KERNELS[auto_kernel(compiled)](compiled, planes)
         return ir.ExecutionResult(out, self.cost(compiled))
 
     def contract(self, compiled, contraction, steps):
-        """One ``pim_contract`` launch, whatever K is (the unrolled kernel
-        has no contraction form)."""
-        if self.mode == "unrolled":
-            super().contract(compiled, contraction, steps)
-        key = register_compiled(compiled)
-        return _run_contract(*_gate_arrays(key), steps,
-                             num_cols=compiled.num_cols,
+        """One ``pim_contract`` launch, whatever K is."""
+        plan = executor_plan(compiled)
+        return _run_contract(*plan.gates, steps, plan=plan,
                              slots=contraction.slots(compiled),
                              interpret=interpret_mode(steps))
 
 
-class PallasUnrolledBackend(PallasBackend):
-    """Forces the wave-scheduled straight-line kernel regardless of size."""
-
-    name = "pallas-unrolled"
-    mode = "unrolled"
-
-
-class PallasLoopBackend(PallasBackend):
-    """Forces the fori_loop kernel (the unrolled mode's perf baseline)."""
-
-    name = "pallas-loop"
-    mode = "loop"
-
-
 ir.register_backend(PallasBackend())
-ir.register_backend(PallasUnrolledBackend())
-ir.register_backend(PallasLoopBackend())

@@ -261,7 +261,7 @@ class CompiledPimFunction:
 
     def __call__(self, *arrays, basis: str = "memristive",
                  passes: tuple[str, ...] = ir.DEFAULT_PASSES,
-                 backend: str | None = None, mode: str | None = None):
+                 backend: str | None = None):
         """Pack ``arrays`` to bit-planes, run the compiled program, unpack.
 
         Each phase is a ``jax.profiler.TraceAnnotation`` span, so a
@@ -281,24 +281,19 @@ class CompiledPimFunction:
                 f"expected {len(self.in_types)} arrays, got {len(arrays)}")
         if isinstance(self.program, ir.Contraction):
             return self._contract(*arrays, basis=basis, passes=passes,
-                                  backend=backend, mode=mode)
+                                  backend=backend)
         n = jnp.shape(arrays[0])[0]
         with jax.profiler.TraceAnnotation(PACK_SPAN):
             planes = bitplanes.pack(self.in_types, *arrays)
         with jax.profiler.TraceAnnotation(EXECUTE_SPAN):
             compiled = self.compiled(basis, passes)
-            name = backend or self.backend
-            if mode is not None and not name.startswith("pallas"):
-                raise ValueError(
-                    f"executor mode {mode!r} only applies to the pallas "
-                    f"backends, not {name!r}")
-            opts = {} if mode is None else {"mode": mode}
-            out = ir.get_backend(name).run(compiled, planes, **opts).planes
+            out = ir.get_backend(backend or self.backend).run(
+                compiled, planes).planes
         with jax.profiler.TraceAnnotation(UNPACK_SPAN):
             results = bitplanes.unpack(self.out_types, out, n)
         return results[0] if len(results) == 1 else results
 
-    def _contract(self, a, b, *, basis, passes, backend, mode):
+    def _contract(self, a, b, *, basis, passes, backend):
         """``a [M, K] @ b [K, N]`` → ``[M, N]`` in the input dtype: a serial
         MAC contraction with a fixed rounding order, not a tree reduction.
         ``acc = +0``, then for ``k = 0 .. K-1`` in order
@@ -317,8 +312,6 @@ class CompiledPimFunction:
             raise ValueError(
                 f"a @ b takes a [M, K] and b [K, N] with M, K, N >= 1, "
                 f"got shapes {shapes[0]} and {shapes[1]}")
-        if mode is not None:
-            raise ValueError(f"a contraction has one kernel, no mode {mode!r}")
         t = self.in_types[0]
         with jax.profiler.TraceAnnotation(PACK_SPAN):
             steps = bitplanes.pack_contraction(t, a, b)

@@ -1,10 +1,11 @@
 """``chip_smoke.py`` on the CPU: the script refuses to run without a TPU,
 and its phases — inputs with inf, NaN and subnormals, the numpy host
 oracle, the bit-for-bit comparison — pass at a tiny size with the kernels
-in interpret mode.  The forced-unrolled f32 MAC phase is left out here: its
-13 straight-line segments take about two minutes to compile on XLA-CPU, and
-``test_executor.py::test_fused_f32_mac_unrolled_bit_exact`` checks that
-kernel bit for bit."""
+in interpret mode.  The f32 MAC phase on the unrolled kernel is left out
+here: its 13 straight-line segments take about two minutes to compile on
+XLA-CPU, and ``test_executor.py::test_fused_f32_mac_unrolled_bit_exact``
+checks that kernel bit for bit; the int8 MAC stands in for it on each
+kernel."""
 
 import dataclasses
 import os
@@ -45,3 +46,15 @@ def test_phase_on_cpu(phase):
         assert result["subnormal_out"] and result["nan_out"] and result["inf_out"]
     if isinstance(small, chip_smoke.Matmul):
         assert result["f32_accurate"], result
+
+
+@pytest.mark.parametrize("kernel", ["loop", "unrolled"])
+def test_phase_on_one_kernel_on_cpu(kernel):
+    """A phase that names its kernel runs that entry point between the
+    frontend's pack and unpack, and reports it."""
+    int8_mac = next(p for p in chip_smoke.PHASES if p.name == "int8_mac_dram")
+    small = dataclasses.replace(int8_mac, kernel=kernel, elements=_SMALL)
+    result = chip_smoke.run_phase(small)
+    assert result["mismatches"] == 0, result
+    assert result["kernel"] == kernel
+    assert result["segments"] >= 1

@@ -1,9 +1,9 @@
-"""Executor modes and gate scheduling (DESIGN.md §5): bit-exactness of the
-wave-scheduled ``pallas-unrolled`` kernel vs the ``pallas-loop`` fori_loop
-kernel vs the interpreter oracle — across the ``_OP_TABLE``, fused MAC
-programs, both logic bases and every frontend dtype — plus the
+"""Executor kernels and gate scheduling (DESIGN.md §5): bit-exactness of the
+wave-scheduled straight-line kernel (``run_unrolled``) vs the fori_loop
+kernel (``run_loop``) vs the interpreter oracle — across the ``_OP_TABLE``,
+fused MAC programs, both logic bases and every frontend dtype — plus the
 ``levelize``/``reorder`` pass invariants (topological order preserved, peak
-columns never increased) and the per-key schedule artifact caches."""
+columns never increased) and the per-schedule executor plans."""
 
 import numpy as np
 import pytest
@@ -11,17 +11,17 @@ import pytest
 import jax.numpy as jnp
 
 import repro.pim as pim
-from repro.core import aritpim, ir
+from repro.core import aritpim, bitplanes, ir
 from repro.core.machine import operand_slots
 from repro.kernels import pim_bitserial
 
 np.seterr(all="ignore")
 
-# Forced-unrolled parity is bounded: straight-line XLA-CPU compile time is
-# superlinear in schedule length, and schedules past the auto threshold fall
-# back to the loop kernel in production anyway (which the same test still
-# checks).  The bound still covers every opcode on both bases and
-# multi-segment straight-line kernels (> UNROLL_SEGMENT_GATES gates).
+# Unrolled-kernel parity is bounded: straight-line XLA-CPU compile time is
+# superlinear in schedule length, and the backend runs schedules past the
+# threshold on the loop kernel anyway (which the same test still checks).
+# The bound still covers every opcode on both bases and multi-segment
+# straight-line kernels (> UNROLL_SEGMENT_GATES gates).
 _UNROLL_TEST_CAP = 2500
 
 _STRIPPED = tuple(p for p in ir.DEFAULT_PASSES if p != "reorder")
@@ -42,7 +42,15 @@ def _random_planes(n_planes, n_words, seed):
     )
 
 
-# ------------------------------------------------------------ mode parity
+def _call_on(kernel, fn, args, basis="memristive"):
+    """``fn(*args)`` with its schedule run on ``KERNELS[kernel]``: the
+    frontend's pack and unpack around one kernel entry point."""
+    planes = bitplanes.pack(fn.in_types, *args)
+    out = pim_bitserial.KERNELS[kernel](fn.compiled(basis), planes)
+    return bitplanes.unpack(fn.out_types, out, jnp.shape(args[0])[0])[0]
+
+
+# ---------------------------------------------------------- kernel parity
 
 
 @pytest.mark.parametrize("basis", ["memristive", "dram"])
@@ -58,16 +66,14 @@ def test_executor_modes_bit_exact_all_ops(op, basis):
     planes = _random_planes(wa + wb, 2, seed=sum(map(ord, op + basis)))
     exp = np.asarray(ir.get_backend("interpreter").run(compiled, planes).planes)
 
-    got_loop = np.asarray(
-        ir.get_backend("pallas-loop").run(compiled, planes).planes)
+    got_loop = np.asarray(pim_bitserial.run_loop(compiled, planes))
     assert np.array_equal(got_loop, exp), (op, basis, "loop")
 
     if compiled.num_gates <= _UNROLL_TEST_CAP:
-        got_unrolled = np.asarray(
-            ir.get_backend("pallas-unrolled").run(compiled, planes).planes)
+        got_unrolled = np.asarray(pim_bitserial.run_unrolled(compiled, planes))
         assert np.array_equal(got_unrolled, exp), (op, basis, "unrolled")
     else:
-        assert pim_bitserial.resolve_mode(compiled) == "loop", (
+        assert pim_bitserial.auto_kernel(compiled) == "loop", (
             op, compiled.num_gates)
 
     got_auto = np.asarray(
@@ -93,8 +99,8 @@ def test_fused_mac_unrolled_bit_exact(dtype, basis):
     else:
         bits = [rng.integers(0, 2**16, 70, dtype=np.uint32) for _ in range(3)]
         args = [jnp.asarray(b.astype(np.uint16)).view(jnp.bfloat16) for b in bits]
-    got_u = mac(*args, basis=basis, backend="pallas-unrolled")
-    got_l = mac(*args, basis=basis, backend="pallas-loop")
+    got_u = _call_on("unrolled", mac, args, basis)
+    got_l = _call_on("loop", mac, args, basis)
     got_i = mac(*args, basis=basis, backend="interpreter")
     vu, vl, vi = (
         np.asarray(x).view(np.uint16) if dt.kind == "bf16" else np.asarray(x)
@@ -103,22 +109,24 @@ def test_fused_mac_unrolled_bit_exact(dtype, basis):
     assert np.array_equal(vl, vi), (dtype, basis)
 
 
-@pytest.mark.parametrize("mode", ["unrolled", "loop"])
-def test_fused_mac_under_outer_jit(mode):
+@pytest.mark.parametrize("kernel", ["unrolled", "loop"])
+def test_fused_mac_under_outer_jit(kernel):
     """A program called inside the caller's ``jax.jit`` runs the same
     kernels (traced arrays pick interpret mode from the default backend)."""
     import jax
 
     mac = pim.compile(_MAC, dtype=pim.int8)
     x = jnp.asarray(np.arange(-40, 40, dtype=np.int32))
-    jitted = jax.jit(lambda a, b, c: mac(a, b, c, basis="dram", mode=mode))
-    got = np.asarray(jitted(x, x[::-1], x))
-    exp = np.asarray(mac(x, x[::-1], x, basis="dram", mode=mode))
+    args = (x, x[::-1], x)
+    jitted = jax.jit(lambda *a: _call_on(kernel, mac, a, "dram"))
+    got = np.asarray(jitted(*args))
+    exp = np.asarray(_call_on(kernel, mac, args, "dram"))
     assert np.array_equal(got, exp)
+    assert np.array_equal(got, np.asarray(mac(*args, basis="dram")))
 
 
 def test_fused_f32_mac_unrolled_bit_exact():
-    """The flagship 13k-gate f32 fused MAC: the forced straight-line kernel
+    """The flagship 13k-gate f32 fused MAC: the straight-line kernel
     (multi-segment) reproduces the interpreter bit-for-bit.  One basis —
     this is the most expensive straight-line compile in the suite."""
     mac = pim.compile(_MAC, dtype=pim.f32)
@@ -126,7 +134,7 @@ def test_fused_f32_mac_unrolled_bit_exact():
     args = [jnp.asarray(
         rng.integers(0, 2**32, 96, dtype=np.uint64).astype(np.uint32)
         .view(np.float32)) for _ in range(3)]
-    got_u = np.asarray(mac(*args, backend="pallas-unrolled")).view(np.uint32)
+    got_u = np.asarray(_call_on("unrolled", mac, args)).view(np.uint32)
     got_i = np.asarray(mac(*args, backend="interpreter")).view(np.uint32)
     assert np.array_equal(got_u, got_i)
 
@@ -141,7 +149,7 @@ def test_loop_kernel_word_blocks_bit_exact(words, basis):
     compiled = pim.compile(_MAC, dtype=pim.f32).compiled(basis=basis)
     planes = _random_planes(len(compiled.input_slots), words, seed=words)
     exp = np.asarray(ir.get_backend("interpreter").run(compiled, planes).planes)
-    got = ir.get_backend("pallas-loop").run(compiled, planes).planes
+    got = pim_bitserial.run_loop(compiled, planes)
     assert got.shape == exp.shape
     assert np.array_equal(np.asarray(got), exp)
 
@@ -298,59 +306,60 @@ def test_auto_mode_threshold():
     small = ir.compile_op("fixed_add", 8)
     big = ir.compile_op("float_div", 32)
     assert small.num_gates <= pim_bitserial.UNROLL_AUTO_MAX_GATES
-    assert pim_bitserial.resolve_mode(small) == "unrolled"
-    assert pim_bitserial.resolve_mode(big) == "loop"
-    assert pim_bitserial.resolve_mode(big, "unrolled") == "unrolled"
-    with pytest.raises(ValueError, match="executor mode"):
-        pim_bitserial.resolve_mode(small, "turbo")
+    assert big.num_gates > pim_bitserial.UNROLL_AUTO_MAX_GATES
+    assert pim_bitserial.auto_kernel(small) == "unrolled"
+    assert pim_bitserial.auto_kernel(big) == "loop"
 
 
-# ------------------------------------------------------- schedule caches
+# ------------------------------------------------------- executor plans
 
 
 def test_gate_arrays_cached_per_key():
+    """The executor plan, its device gate arrays and its segments are built
+    once per ``CompiledSchedule`` object; another schedule gets its own."""
     compiled = ir.compile_op("fixed_add", 8)
-    key = pim_bitserial.register_compiled(compiled)
-    a1 = pim_bitserial._gate_arrays(key)
-    a2 = pim_bitserial._gate_arrays(key)
-    assert a1 is a2  # built and uploaded once, reused
-    # re-registering the same object keeps the cache ...
-    pim_bitserial.register_compiled(compiled)
-    assert pim_bitserial._gate_arrays(key) is a1
-    # ... registering a different schedule under the key invalidates it
+    plan = pim_bitserial.executor_plan(compiled)
+    assert pim_bitserial.executor_plan(compiled) is plan
+    assert pim_bitserial.executor_plan(compiled).gates is plan.gates
+    assert plan.segments is plan.segments
+    assert plan.input_slots == tuple(compiled.input_slots)
+    assert plan.output_slots == tuple(compiled.output_slots)
+    assert [np.asarray(g).tolist() for g in plan.gates] == (
+        compiled.ops.T.tolist())
+    # the same program compiled with other passes is another schedule
     clone = ir.compile_op("fixed_add", 8, passes=())
-    pim_bitserial.register_schedule(key, clone)
-    assert pim_bitserial._gate_arrays(key) is not a1
-    pim_bitserial.register_compiled(compiled)  # restore
+    assert pim_bitserial.executor_plan(clone) is not plan
 
 
 def test_run_schedule_plane_count_error():
     compiled = ir.compile_op("fixed_add", 8)
-    key = pim_bitserial.register_compiled(compiled)
     planes = _random_planes(3, 2, seed=0)
     with pytest.raises(ValueError, match="expects 16 stacked input planes"):
-        pim_bitserial.run_schedule(key, planes)
+        ir.get_backend("pallas").run(compiled, planes)
 
 
 def test_rebound_key_does_not_replay_stale_kernel():
-    """Re-registering different schedule content under an existing key must
-    bump the generation so jit traces that baked the old gate list (or slot
-    maps) are never replayed, in either executor mode."""
-    from repro.core import bitplanes
+    """Two programs with the same traced name and different bodies never
+    share a kernel trace: run alternately in one process, on both kernel
+    entry points, each gives its own result."""
+    # Two ops each, so neither canonicalizes to a single op's name.
+    def prog(a, b):
+        return a + b + a
 
-    add = ir.compile_op("fixed_add", 8)
-    sub = ir.compile_op("fixed_sub", 8)
-    x = np.array([10, 7], np.int32)
-    y = np.array([3, 2], np.int32)
-    planes = jnp.stack(bitplanes.int_to_planes(jnp.asarray(x), 8)
-                       + bitplanes.int_to_planes(jnp.asarray(y), 8))
+    add = pim.compile(prog, dtype=pim.int8)
 
-    def run(mode):
-        out = pim_bitserial.run_schedule("rebound", planes, mode=mode)
-        return bitplanes.planes_to_int(
-            [out[i] for i in range(8)], 2, signed=True).tolist()
+    def prog(a, b):  # noqa: F811 — the same name, another body
+        return a - b + a
 
-    pim_bitserial.register_schedule("rebound", add)
-    assert run("unrolled") == [13, 9] and run("loop") == [13, 9]
-    pim_bitserial.register_schedule("rebound", sub)
-    assert run("unrolled") == [7, 5] and run("loop") == [7, 5]
+    sub = pim.compile(prog, dtype=pim.int8)
+    assert add.program.name == sub.program.name == "prog"
+    x = jnp.asarray(np.array([10, 7], np.int32))
+    y = jnp.asarray(np.array([3, 2], np.int32))
+
+    def run(kernel, fn):
+        return np.asarray(_call_on(kernel, fn, (x, y))).tolist()
+
+    for _ in range(2):
+        for kernel in ("unrolled", "loop"):
+            assert run(kernel, add) == [23, 16]
+            assert run(kernel, sub) == [17, 12]

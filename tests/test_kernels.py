@@ -6,9 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import aritpim, bitplanes
+from repro.core import aritpim, bitplanes, ir
 from repro.kernels import ops, ref
-from repro.kernels import pim_bitserial
 
 np.seterr(all="ignore")
 
@@ -39,16 +38,15 @@ def test_bitserial_fixed_add_sweep(nbits):
 
 def test_bitserial_matches_scan_oracle():
     """Pallas executor vs machine.execute_schedule on the same schedule."""
-    key = "float_mul32"
     sched = aritpim.build_schedule("float_mul", compress=True)
-    pim_bitserial.register_schedule(key, sched)
+    compiled = ir.CompiledSchedule.from_legacy(sched, key="float_mul32")
     rng = np.random.default_rng(7)
     x = rng.normal(size=64).astype(np.float32)
     y = rng.normal(size=64).astype(np.float32)
     planes = jnp.stack(
         bitplanes.f32_to_planes(jnp.asarray(x)) + bitplanes.f32_to_planes(jnp.asarray(y))
     )
-    got = pim_bitserial.run_schedule(key, planes)
+    got = ir.get_backend("pallas").run(compiled, planes).planes
     oracle = ref.bitserial_ref(sched, planes)
     assert np.array_equal(np.asarray(got), np.asarray(oracle))
 

@@ -161,11 +161,11 @@ def test_contract_kernel_takes_step_planes_and_returns_the_result():
     planes: no per-step accumulator array in HBM."""
     fn = pim.compile(_MATMUL, dtype=pim.f32)
     compiled = fn.compiled("memristive")
-    key = pim_bitserial.register_compiled(compiled)
+    plan = pim_bitserial.executor_plan(compiled)
     steps = jax.ShapeDtypeStruct((5, 64, 512), jnp.uint32)
     jaxpr = jax.make_jaxpr(lambda s: pim_bitserial._run_contract(
-        *pim_bitserial._gate_arrays(key), s, num_cols=compiled.num_cols,
-        slots=fn.program.slots(compiled), interpret=True))(steps)
+        *plan.gates, s, plan=plan, slots=fn.program.slots(compiled),
+        interpret=True))(steps)
     assert [v.aval.shape for v in jaxpr.jaxpr.outvars] == [(32, 512)]
     inner = jaxpr.jaxpr.eqns[0].params["jaxpr"]
     calls = [e for e in inner.eqns if e.primitive.name == "pallas_call"]

@@ -192,6 +192,16 @@ def test_trace_errors():
         pim.compile(lambda a, b: a + "one", dtype=pim.f32)
     with pytest.raises(pim.TraceError):  # non-integral constant in fixed
         pim.compile(lambda a, b: a + 1.5, dtype=pim.int8)
+
+
+@pytest.mark.parametrize("program", ["add", "matmul"])
+def test_call_takes_no_executor_mode(program):
+    """The backend picks the kernel; a call has no ``mode`` to force one."""
+    fn = pim.compile((lambda a, b: a + b) if program == "add"
+                     else (lambda a, b: a @ b), dtype=pim.int8)
+    x = np.ones((2, 2), np.int32)
+    with pytest.raises(TypeError, match="mode"):
+        fn(x, x, mode="loop")
     with pytest.raises(pim.TraceError):
         pim.compile(lambda a, b: a + b, dtype=(pim.f32, pim.bf16))
     with pytest.raises(KeyError):  # no bf16 division netlist registered
@@ -202,10 +212,6 @@ def test_trace_errors():
         pim.compile(lambda *args: args[0] + args[1], dtype=pim.f32)
     with pytest.raises(pim.TraceError, match="overflows"):  # 10**400 > f64
         pim.compile(lambda a: a + 10**400, dtype=pim.f32)
-    with pytest.raises(ValueError, match="only applies to the pallas"):
-        pim.compile(lambda a, b: a + b, dtype=pim.int8)(
-            np.arange(3, dtype=np.int32), np.arange(3, dtype=np.int32),
-            backend="interpreter", mode="unrolled")
 
 
 # --------------------------------------------------- scalar constants

@@ -75,20 +75,19 @@ def _assert_mosaic(lowered, kernel=None):
                    for line in calls), calls
 
 
-def _register(fn, dtype, basis):
+def _plan(fn, dtype, basis):
     compiled = pim.compile(fn, dtype=dtype).compiled(basis=basis)
-    return compiled, pim_bitserial.register_compiled(compiled)
+    return compiled, pim_bitserial.executor_plan(compiled)
 
 
 @pytest.mark.parametrize("dtype,basis", [(pim.f32, "memristive"),
                                          (pim.int8, "dram")],
                          ids=["f32_mac-memristive", "int8_mac-dram"])
 def test_unrolled_segment_compiles(one_chip, dtype, basis):
-    compiled, key = _register(_MAC, dtype, basis)
+    compiled, plan = _plan(_MAC, dtype, basis)
     state = _sds((compiled.num_cols, W), jnp.uint32, one_chip)
     lowered = pim_bitserial._run_unrolled_segment.lower(
-        state, schedule_key=key, gen=pim_bitserial._GENERATIONS.get(key, 0),
-        seg=0, interpret=False)
+        state, plan=plan, seg=0, interpret=False)
     _assert_mosaic(lowered, kernel="pim_segment_0")
 
 
@@ -97,15 +96,14 @@ def test_loop_kernel_compiles(one_chip, op):
     """The SMEM gate arrays fit for the MAC and the longest schedule
     (``float_div`` on the memristive basis, ~19.5k gates)."""
     if op == "f32_mac":
-        compiled, key = _register(_MAC, pim.f32, "memristive")
+        compiled, plan = _plan(_MAC, pim.f32, "memristive")
     else:
         compiled = ir.compile_op("float_div", 32)
-        key = pim_bitserial.register_compiled(compiled)
+        plan = pim_bitserial.executor_plan(compiled)
     gates = [_sds((compiled.num_gates,), jnp.int32, one_chip)] * 5
     planes = _sds((len(compiled.input_slots), W), jnp.uint32, one_chip)
-    lowered = pim_bitserial._run.lower(
-        *gates, planes, schedule_key=key,
-        gen=pim_bitserial._GENERATIONS.get(key, 0), interpret=False)
+    lowered = pim_bitserial._run.lower(*gates, planes, plan=plan,
+                                       interpret=False)
     _assert_mosaic(lowered, kernel="pim_loop")
 
 
@@ -118,7 +116,7 @@ def test_contract_kernel_compiles(one_chip, basis):
     gates = [_sds((compiled.num_gates,), jnp.int32, one_chip)] * 5
     steps = _sds((3, 64, W), jnp.uint32, one_chip)
     lowered = pim_bitserial._run_contract.lower(
-        *gates, steps, num_cols=compiled.num_cols,
+        *gates, steps, plan=pim_bitserial.executor_plan(compiled),
         slots=fn.program.slots(compiled), interpret=False)
     _assert_mosaic(lowered, kernel="pim_contract")
 
@@ -145,17 +143,16 @@ def test_widest_tiles_compile(one_chip, kernel, basis):
     words = 2 * u_max * pim_bitserial.TILE_WORDS
     assert pim_bitserial.loop_block_units(words, vmem_planes) == u_max > 1
     gates = [_sds((compiled.num_gates,), jnp.int32, one_chip)] * 5
+    plan = pim_bitserial.executor_plan(compiled)
     if kernel == "pim_contract":
         steps = _sds((2, n_in, words), jnp.uint32, one_chip)
         lowered = pim_bitserial._run_contract.lower(
-            *gates, steps, num_cols=compiled.num_cols,
-            slots=fn.program.slots(compiled), interpret=False)
+            *gates, steps, plan=plan, slots=fn.program.slots(compiled),
+            interpret=False)
     else:
-        key = pim_bitserial.register_compiled(compiled)
         planes = _sds((n_in, words), jnp.uint32, one_chip)
-        lowered = pim_bitserial._run.lower(
-            *gates, planes, schedule_key=key,
-            gen=pim_bitserial._GENERATIONS.get(key, 0), interpret=False)
+        lowered = pim_bitserial._run.lower(*gates, planes, plan=plan,
+                                           interpret=False)
     _assert_mosaic(lowered, kernel=kernel)
 
 
